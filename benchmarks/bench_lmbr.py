@@ -184,7 +184,7 @@ def _tier_rows(tier, hg, n, capacity, max_moves, ref_budget=None):
 def run(quick: bool = True) -> list[dict]:
     from repro.core.setcover import _accel_backend
 
-    _accel_backend()  # pay the one-time jax import outside the timings
+    _accel_backend()  # pay JAX's one-time backend start-up outside the timings
     rows = []
     # fig6 quick tier: paper Random defaults, bounded move budget
     wl = random_workload(1000, 4000, 3, 11, 20, seed=0)

@@ -83,7 +83,7 @@ def _one_run(sim, hg, old, mplan, engine: str):
 def run(quick: bool = True) -> list[dict]:
     from repro.core.setcover import _accel_backend
 
-    _accel_backend()  # pay the one-time jax import outside the timings
+    _accel_backend()  # pay JAX's one-time backend start-up outside the timings
     wl = lmbr_stress_workload()
     hg = wl.hypergraph
     n = LMBR_STRESS_DEFAULTS["num_partitions"]

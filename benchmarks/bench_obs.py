@@ -93,7 +93,7 @@ def _full_route(member: np.ndarray, hg):
 def run(quick: bool = True) -> list[dict]:
     from repro.core.setcover import _accel_backend
 
-    _accel_backend()  # pay the one-time jax import outside the timings
+    _accel_backend()  # pay JAX's one-time backend start-up outside the timings
     flags.reset()
     obs.reset()
 

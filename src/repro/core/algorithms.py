@@ -23,13 +23,13 @@ import numpy as np
 
 from .. import flags as _flags
 from .. import obs as _obs
+from ..kernels.lockstep_peel.ops import lockstep_peel
 from . import hpa as hpa_mod
 from .cluster import capacity_vector, normalize_capacity
 from .hypergraph import Hypergraph
 from .setcover import (
     Placement,
     SpanMaintainer,
-    _accel_backend,
     batched_spans_csr,
     engine_counters,
     greedy_set_cover,
@@ -461,7 +461,8 @@ class _LMBRState:
                  or (np.all(nw == np.rint(nw)) and float(nw.sum()) < 2 ** 24))
         )
         self.stats = dict(gain_calls=0, gain_cache_hits=0, gain_fp_hits=0,
-                          peel_pairs=0, moves=0)
+                          peel_pairs=0, moves=0, dense_peel_calls=0,
+                          dense_peel_skips=0, int_exact=self._int_exact)
 
     @property
     def part_edges(self) -> list[set[int]]:
@@ -636,15 +637,14 @@ class _LMBRState:
 
     def _peel_with_traj(self, proj: list[tuple], backend: str):
         """Peel projected pairs, returning {key: (pool, fp, traj)}.  The
-        dense device backends only engage on the integer-exact domain (and
-        with jax importable); everything else — including fallback — runs
-        the flat numpy lockstep with trajectory recording."""
-        if (backend in ("device", "pallas") and self._int_exact
-                and _accel_backend() is not None):
-            try:
+        dense device backends only engage on the integer-exact domain; a
+        requested device peel outside it is counted in ``dense_peel_skips``
+        and runs the flat numpy lockstep with trajectory recording, as every
+        other backend does."""
+        if backend in _DENSE_PEELS:
+            if self._int_exact:
                 return _lmbr_peel_dense(self, proj, backend)
-            except Exception:
-                pass  # fall through to the bit-identical flat engine
+            self.stats["dense_peel_skips"] += 1
         return _lmbr_peel_flat(self, proj, collect_traj=True)
 
     def _max_gain_many_item(self, pairs, use_cache: bool):
@@ -1049,17 +1049,20 @@ def _lmbr_gain_batch(state: _LMBRState, pairs: list[tuple[int, int]]):
     return out
 
 
+# lmbr_peel values that run the dense lockstep peel -> lockstep_peel backend
+_DENSE_PEELS = {"device": "jax", "pallas": "pallas", "interpret": "interpret"}
+
+
 def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
-    """Device-resident lockstep peel (``lmbr_peel="device"|"pallas"``):
+    """Device-resident lockstep peel (``lmbr_peel="device"|"pallas"|
+    "interpret"``):
     densify each pair's projection into a (K, U) incidence cell and run
     every round on device via ``repro.kernels.lockstep_peel``.  Only the
     free-space-independent trajectories come back; selection happens in
     ``_eval_traj``.  Caller guarantees the integer-exact weight domain, so
     the f32 trajectories are bit-identical to the flat f64 engine's.
     Returns {key: (pool, fp, traj)} like ``_lmbr_peel_flat``."""
-    from ..kernels.lockstep_peel.ops import lockstep_peel
-
-    force = "jax" if backend == "device" else "pallas"
+    force = _DENSE_PEELS[backend]
     node_w = state.hg.node_weights
     out: dict[tuple[int, int], tuple] = {}
     classes: dict[tuple[int, int], list[tuple]] = {}
@@ -1068,8 +1071,9 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
         u2 = 1 << max(2, (len(p[3]) - 1).bit_length())
         k2 = 1 << max(2, (len(p[2]) - 1).bit_length())
         # a single pathological pair can dwarf the batch; densifying it
-        # would blow memory, so it keeps the flat CSR engine
-        if u2 * k2 > 1 << 22:
+        # would blow memory (and a side past 2^12 makes a kernel whose
+        # compile runs for minutes), so it keeps the flat CSR engine
+        if u2 * k2 > 1 << 22 or max(u2, k2) > 1 << 12:
             huge.append(p)
         else:
             classes.setdefault((u2, k2), []).append(p)
@@ -1089,6 +1093,7 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
                 nwm[i, : len(uniq)] = node_w[uniq]
                 nv[i] = len(uniq)
             peel, rtot, rben = lockstep_peel(inc, wem, nwm, nv, force=force)
+            state.stats["dense_peel_calls"] += 1
             done = peel < 0  # -1s are a suffix: active never resumes
             for i, p in enumerate(sub):
                 R = int(np.argmax(done[i])) if done[i].any() else peel.shape[1]

@@ -42,10 +42,14 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .. import flags as _flags
 from .. import obs as _obs
+from ..kernels.span_gain.ops import span_gains
 from .cluster import normalize_capacity
 
 __all__ = [
@@ -240,29 +244,45 @@ def query_span(query: np.ndarray, member: np.ndarray) -> int:
 
 
 # ===================================================================== engine
+# Engine-level dispatch counters (observability, not control flow): how many
+# word-count buckets resolved on which cover loop, how many greedy rounds
+# each side ran, and how many host-loop gain rounds each gain backend
+# computed.  `lmbr`/`PlacementService` snapshot deltas into Placement.stats;
+# benchmarks read them to report transfer counts, and a run that pins a
+# device path reads them to prove the path ran.
+ENGINE_COUNTERS = {
+    "device_buckets": 0,
+    "host_buckets": 0,
+    "device_rounds": 0,
+    "host_rounds": 0,
+    "numpy_gain_rounds": 0,
+    "jax_gain_rounds": 0,
+    "pallas_gain_rounds": 0,
+    "interpret_gain_rounds": 0,
+}
+
+
 def _gains_numpy(codes: np.ndarray, rem: np.ndarray) -> np.ndarray:
     """Popcount gains: codes (A, N, W) uint64, rem (A, W) -> (A, N) int64."""
     return np.bitwise_count(codes & rem[:, None, :]).sum(axis=2, dtype=np.int64)
 
 
-_ACCEL_BACKEND = None  # resolved once: "pallas" on TPU, "jax" elsewhere
+def _accel_backend() -> str:
+    """The accelerated gain backend of this process: the compiled Pallas
+    kernel on TPU, the jitted jnp popcount on any other JAX backend."""
+    return "pallas" if jax.default_backend() == "tpu" else "jax"
 
 
-def _accel_backend() -> str | None:
-    """Pick the accelerated gain backend available on this host (None if jax
-    is missing or fails to initialize — the numpy oracle then serves every
-    bucket; all backends are bit-identical, so this only costs speed)."""
-    global _ACCEL_BACKEND
-    if _ACCEL_BACKEND is None:
-        try:
-            import jax
-
-            _ACCEL_BACKEND = (
-                "pallas" if jax.default_backend() == "tpu" else "jax"
-            )
-        except Exception:  # no jax, or a broken accelerator runtime
-            _ACCEL_BACKEND = "none"
-    return None if _ACCEL_BACKEND == "none" else _ACCEL_BACKEND
+def _resolve_gain_backend(words: int) -> str:
+    """Per-round gain backend: ``span_backend`` if pinned, else numpy below
+    ``span_dispatch_threshold`` gain-matrix words and the accelerated
+    backend above it.  Counts the pick in ``ENGINE_COUNTERS``."""
+    backend = _flags.FLAGS.get("span_backend", "auto")
+    if backend == "auto":
+        thresh = int(_flags.FLAGS.get("span_dispatch_threshold", 48_000))
+        backend = "numpy" if words < thresh else _accel_backend()
+    ENGINE_COUNTERS[f"{backend}_gain_rounds"] += 1
+    return backend
 
 
 def _gain_matrix_w1(codes1: np.ndarray, rem1: np.ndarray) -> np.ndarray:
@@ -270,20 +290,10 @@ def _gain_matrix_w1(codes1: np.ndarray, rem1: np.ndarray) -> np.ndarray:
     (A,) -> (A, N) gains.  Same per-round dispatch rule; the numpy path
     skips the word-axis reduction (gain values are identical, only the
     dtype differs — argmax/zero tests are unaffected)."""
-    backend = _flags.FLAGS.get("span_backend", "auto")
-    if backend == "auto":
-        thresh = int(_flags.FLAGS.get("span_dispatch_threshold", 48_000))
-        backend = "numpy" if codes1.size < thresh else (
-            _accel_backend() or "numpy"
-        )
+    backend = _resolve_gain_backend(codes1.size)
     if backend == "numpy":
         return np.bitwise_count(codes1 & rem1[:, None])
-    try:
-        from ..kernels.span_gain.ops import span_gains
-
-        return span_gains(codes1[:, :, None], rem1[:, None], force=backend)
-    except Exception:
-        return np.bitwise_count(codes1 & rem1[:, None])
+    return span_gains(codes1[:, :, None], rem1[:, None], force=backend)
 
 
 def _gain_matrix(codes: np.ndarray, rem: np.ndarray) -> np.ndarray:
@@ -296,35 +306,10 @@ def _gain_matrix(codes: np.ndarray, rem: np.ndarray) -> np.ndarray:
     calibrated span_dispatch_threshold run on the accelerated backend — the
     Pallas span_gain kernel on TPU, the jitted jnp popcount elsewhere.
     """
-    backend = _flags.FLAGS.get("span_backend", "auto")
-    if backend == "auto":
-        thresh = int(_flags.FLAGS.get("span_dispatch_threshold", 48_000))
-        backend = "numpy" if codes.size < thresh else (
-            _accel_backend() or "numpy"
-        )
+    backend = _resolve_gain_backend(codes.size)
     if backend == "numpy":
         return _gains_numpy(codes, rem)
-    try:
-        from ..kernels.span_gain.ops import span_gains
-
-        return span_gains(codes, rem, force=backend)
-    except Exception:
-        # no jax / broken accelerator runtime: the numpy oracle is
-        # bit-identical, so degrade silently to it rather than fail placement
-        return _gains_numpy(codes, rem)
-
-
-# ---------------------------------------------- device-resident round loop
-# Engine-level dispatch counters (observability, not control flow): how many
-# word-count buckets resolved on which cover loop and how many greedy rounds
-# each side ran.  `lmbr`/`PlacementService` snapshot deltas into
-# Placement.stats; benchmarks read them to report transfer counts.
-ENGINE_COUNTERS = {
-    "device_buckets": 0,
-    "host_buckets": 0,
-    "device_rounds": 0,
-    "host_rounds": 0,
-}
+    return span_gains(codes, rem, force=backend)
 
 
 def engine_counters() -> dict:
@@ -332,6 +317,7 @@ def engine_counters() -> dict:
     return dict(ENGINE_COUNTERS)
 
 
+# ---------------------------------------------- device-resident round loop
 _ROUND_LOOPS: dict[tuple[int, int, int, int], object] = {}
 
 
@@ -343,6 +329,12 @@ def _round_loop_fn(B: int, N: int, W2: int, Rmax: int):
     the bucket inside one `lax.while_loop`, so cover state (remaining-bit
     words, chosen matrix) stays device-resident: one upload of the packed
     codes, one download of the chosen matrix, zero per-round transfers.
+
+    Layout: codes arrive word-major, (W2, B, N) uint32, so the (B, N)
+    minor dims tile the TPU's (8, 128) vreg and the word reduce is
+    elementwise across planes.  Compiled for a v5e at B=65536, N=256,
+    W2=2, the loop's temporaries take 0.5 MiB this way against 32 MiB with
+    the 2-wide word axis minor.
 
     Exactness contract (mirrors the host loop bit-for-bit): gains are
     integer popcounts summed over uint32 lanes, `argmax` takes the first
@@ -361,11 +353,8 @@ def _round_loop_fn(B: int, N: int, W2: int, Rmax: int):
         reg.inc("jit_retraces", shape=f"B{B}.N{N}.W{W2}.R{Rmax}")
         _obs.tracer().event("jit.retrace", kernel="cover_round_loop",
                             B=B, N=N, W=W2, Rmax=Rmax)
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
 
-    def loop(codes, rem):  # codes (B, N, W2) uint32, rem (B, W2) uint32
+    def loop(codes, rem):  # codes (W2, B, N) uint32, rem (W2, B) uint32
         ch0 = jnp.full((Rmax, B), -1, dtype=jnp.int32)
         bad0 = jnp.zeros((B,), dtype=bool)
 
@@ -375,21 +364,24 @@ def _round_loop_fn(B: int, N: int, W2: int, Rmax: int):
 
         def body(state):
             r, rem, ch, bad = state
-            active = jnp.any(rem != 0, axis=1)
+            active = jnp.any(rem != 0, axis=0)
             g = (
-                lax.population_count(codes & rem[:, None, :])
+                lax.population_count(codes & rem[:, :, None])
                 .astype(jnp.int32)
-                .sum(axis=2)
-            )
+                .sum(axis=0)
+            )                                       # (B, N)
             p = jnp.argmax(g, axis=1).astype(jnp.int32)
-            gmax = jnp.take_along_axis(g, p[:, None], axis=1)[:, 0]
-            newbad = active & (gmax == 0)
+            newbad = active & (g.max(axis=1) == 0)
             ok = active & ~newbad
-            sel = jnp.take_along_axis(codes, p[:, None, None], axis=1)[:, 0]
-            rem = jnp.where(ok[:, None], rem & ~sel, rem)
-            # bad rows terminate (their chosen stays -1); the caller falls
-            # back to the host loop to raise the exact engine error
-            rem = jnp.where(newbad[:, None], jnp.uint32(0), rem)
+            # the chosen partition's words by a one-hot masked reduce over
+            # the lane axis: a per-row gather there compiled to a 128 MiB
+            # temporary at B=1024
+            onehot = lax.broadcasted_iota(jnp.int32, (B, N), 1) == p[:, None]
+            sel = jnp.where(onehot[None], codes, jnp.uint32(0)).sum(axis=2)
+            rem = jnp.where(ok[None, :], rem & ~sel, rem)
+            # bad rows terminate (their chosen stays -1); the caller re-runs
+            # the bucket on the host loop to raise the exact engine error
+            rem = jnp.where(newbad[None, :], jnp.uint32(0), rem)
             ch = ch.at[r].set(jnp.where(ok, p, jnp.int32(-1)))
             return r + 1, rem, ch, bad | newbad
 
@@ -405,25 +397,21 @@ def _round_loop_fn(B: int, N: int, W2: int, Rmax: int):
 
 def _device_cover_rounds(codes: np.ndarray, rem: np.ndarray):
     """Resolve one packed bucket on device.  codes (B, N, W) uint64, rem
-    (B, W) uint64 -> ch (B, R) int64, or None to fall back to the host loop
-    (jax unavailable, or a query in the bucket is uncoverable — the host
-    loop then raises the canonical error)."""
+    (B, W) uint64 -> ch (B, R) int64, or None when a query in the bucket is
+    uncoverable (the caller's host loop then raises the canonical error)."""
     B, N, W = codes.shape
     if B == 0:
         return np.zeros((0, 0), dtype=np.int64)
-    try:
-        B2 = 1 << max(3, (B - 1).bit_length())  # pow2 pad bounds jit churn
-        Rmax = min(N, _WORD * W)
-        fn = _round_loop_fn(B2, N, 2 * W, Rmax)
-        c32 = np.zeros((B2, N, 2 * W), dtype=np.uint32)
-        c32[:B] = codes.view(np.uint32).reshape(B, N, 2 * W)
-        r32 = np.zeros((B2, 2 * W), dtype=np.uint32)
-        r32[:B] = rem.view(np.uint32).reshape(B, 2 * W)
-        ch_d, bad_d = fn(c32, r32)
-        ch = np.asarray(ch_d)[:, :B]
-        if np.asarray(bad_d)[:B].any():
-            return None
-    except Exception:
+    B2 = 1 << max(3, (B - 1).bit_length())  # pow2 pad bounds jit churn
+    Rmax = min(N, _WORD * W)
+    fn = _round_loop_fn(B2, N, 2 * W, Rmax)
+    c32 = np.zeros((2 * W, B2, N), dtype=np.uint32)
+    c32[:, :B] = codes.view(np.uint32).reshape(B, N, 2 * W).transpose(2, 0, 1)
+    r32 = np.zeros((2 * W, B2), dtype=np.uint32)
+    r32[:, :B] = rem.view(np.uint32).reshape(B, 2 * W).T
+    ch_d, bad_d = fn(c32, r32)
+    ch = np.asarray(ch_d)[:, :B]
+    if np.asarray(bad_d)[:B].any():
         return None
     used = int((ch >= 0).any(axis=1).sum())  # rounds are prefix-dense
     return ch[:used].T.astype(np.int64)
@@ -508,7 +496,7 @@ def _cover_bucket(edge_ptr, edge_nodes, member, b_idx, W, spans, pin_parts):
     if round_backend == "auto":
         thresh = int(_flags.FLAGS.get("span_round_threshold", 200_000))
         round_backend = "device" if codes.size >= thresh else "numpy"
-    if round_backend == "device" and _accel_backend() is not None:
+    if round_backend == "device":
         ch = _device_cover_rounds(codes, rem)
     if ch is not None:
         ENGINE_COUNTERS["device_buckets"] += 1

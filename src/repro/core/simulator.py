@@ -86,6 +86,9 @@ class SimulationResult:
     online_stats: dict | None = None     # serving counters (run_online)
     active_machines: int = 0             # partitions holding any data
     cluster_power_w: float = 0.0         # steady-state draw (EnergyModel)
+    # serving partition of every pin of the served queries, in trace order
+    # (the replica-selection decision; aligned with the served CSR)
+    pin_parts: np.ndarray | None = None
 
     @property
     def avg_span(self) -> float:
@@ -224,6 +227,7 @@ class Simulator:
             placement_stats=pl.stats,
             active_machines=int((loads > 0).sum()),
             cluster_power_w=self.energy.cluster_power(loads, self.profile),
+            pin_parts=cov.pin_parts,
         )
 
     def run_online(
@@ -465,6 +469,7 @@ class Simulator:
         degraded = 0
         span_total = 0
         spans_parts: list[np.ndarray] = []
+        pin_parts_parts: list[np.ndarray] = []
         total_energy = 0.0
         total_shipped = 0.0
 
@@ -537,6 +542,7 @@ class Simulator:
                 ptr, nodes = sptr, nodes[sidx]
             batch = router.route_csr(ptr, nodes)
             spans_parts.append(batch.spans)
+            pin_parts_parts.append(batch.pin_parts)
             if next_snap:  # running span sum only feeds snapshot gauges
                 span_total += int(batch.spans.sum())
             scanned, shipped = _traffic_gb(
@@ -641,6 +647,10 @@ class Simulator:
             np.concatenate(spans_parts) if spans_parts
             else np.zeros(0, dtype=np.int64)
         )
+        pin_parts = (
+            np.concatenate(pin_parts_parts) if pin_parts_parts
+            else np.zeros(0, dtype=np.int64)
+        )
         live = failover.pl  # the final hot-swapped layout
         final_loads = live.partition_weights()
         return SimulationResult(
@@ -658,6 +668,7 @@ class Simulator:
             cluster_power_w=self.energy.cluster_power(
                 final_loads, self.profile
             ),
+            pin_parts=pin_parts,
         )
 
     def compare(

@@ -29,9 +29,10 @@ FLAGS = {
     # rounds whose word count is below span_dispatch_threshold run on numpy
     # (bitwise_count, the oracle), larger ones on the accelerated path (the
     # Pallas span_gain kernel on TPU, the jitted jnp popcount elsewhere).
-    # "numpy" / "jax" / "pallas" pin one backend globally.  Every backend is
-    # bit-identical, so the flag is purely a performance knob and placement
-    # results never depend on jax being importable.
+    # "numpy" / "jax" / "pallas" pin one backend globally; "pallas" is the
+    # compiled kernel and raises off TPU, "interpret" runs the kernel body
+    # in the Pallas interpreter (CPU tests).  Every backend is bit-identical,
+    # so the flag is purely a performance knob.
     "span_backend": "auto",
     # auto-dispatch crossover, in gain-matrix words (A * N * W) per greedy
     # round.  Calibrated by benchmarks/kernel_bench.py (span_gain_calibration
@@ -49,7 +50,7 @@ FLAGS = {
     # costs one host<->device transfer total instead of one per round.
     # "numpy" / "device" pin one path.  Bit-identical covers either way
     # (integer popcount, argmax ties -> lowest partition id), so this is
-    # purely a performance knob; without jax the numpy loop serves.
+    # purely a performance knob.
     "span_round_backend": "auto",
     # auto crossover for span_round_backend, in packed words (B * N * W)
     # per bucket.  Calibrated by benchmarks/kernel_bench.py
@@ -62,15 +63,17 @@ FLAGS = {
     # CSR peel (flat pin-attribution projection + scatter-add degree
     # updates); "reference" the retained pure-Python oracle; "device" the
     # jitted dense lockstep peel (repro.kernels.lockstep_peel, jnp path);
-    # "pallas" the Pallas lockstep-peel kernel (interpret mode on CPU).
+    # "pallas" the compiled Pallas lockstep-peel kernel (raises off TPU);
+    # "interpret" the same kernel in the Pallas interpreter (CPU tests).
     # Device backends emit the free-space-independent peel TRAJECTORY in
     # integer-exact f32 and the (gain, subset) selection happens on host in
     # f64 — shared with the cache re-evaluation path — so results stay
     # bit-identical (same subsets, same gains, same tie-breaks) and the
     # flag is purely a performance knob; benchmarks/bench_lmbr.py and
     # benchmarks/kernel_bench.py time the backends.  Device peels require
-    # integer-valued weights below 2^24 (asserted per workload) and fall
-    # back to "vector" otherwise, or when jax is unavailable.
+    # integer-valued weights below 2^24 (checked per workload); outside that
+    # domain the pairs run "vector" and the fit's stats count the skip
+    # (dense_peel_skips).
     "lmbr_peel": "vector",
     # LMBR gain-cache granularity.  "item" (default) keys cache validity on
     # a global move tick: each cached (src, dest) entry stores the tick it
@@ -262,7 +265,7 @@ def set_variant(spec: str):
         elif part.startswith("peel"):
             backend = part[len("peel"):]
             if backend not in ("vector", "reference", "auto", "device",
-                               "pallas"):
+                               "pallas", "interpret"):
                 raise ValueError(f"unknown lmbr peel backend {backend!r}")
             FLAGS["lmbr_peel"] = backend
         elif part.startswith("lmbrepoch"):
@@ -372,7 +375,7 @@ def set_variant(spec: str):
             FLAGS["health_anomaly_z"] = z
         elif part.startswith("span"):
             backend = part[len("span"):]
-            if backend not in ("auto", "numpy", "jax", "pallas"):
+            if backend not in ("auto", "numpy", "jax", "pallas", "interpret"):
                 raise ValueError(f"unknown span backend {backend!r}")
             FLAGS["span_backend"] = backend
         else:

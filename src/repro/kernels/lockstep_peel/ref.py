@@ -89,15 +89,20 @@ def lockstep_peel_jnp(inc, we, nodew, nvalid):
 
     Same trajectory contract as ``lockstep_peel_ref``; the early-exit
     ``lax.while_loop`` keeps device round count equal to the longest pair's
-    peel instead of the static U bound.
+    peel instead of the static U bound.  The contractions ask for HIGHEST
+    precision: at the default a TPU rounds f32 matmul operands to bf16,
+    which is inexact for integer weights above 256.
     """
     import jax.numpy as jnp
     from jax import lax
 
+    hi = lax.Precision.HIGHEST
     G, K, U = inc.shape
     iota_u = jnp.arange(U, dtype=jnp.int32)[None, :]
     valid = iota_u < nvalid[:, None]
-    cand0 = jnp.where(valid, jnp.einsum("gku,gk->gu", inc, we), jnp.inf)
+    cand0 = jnp.where(
+        valid, jnp.einsum("gku,gk->gu", inc, we, precision=hi), jnp.inf
+    )
     state0 = (
         jnp.int32(0),
         cand0,
@@ -125,12 +130,14 @@ def lockstep_peel_jnp(inc, we, nodew, nvalid):
         j = jnp.argmin(cand, axis=1).astype(jnp.int32)
         onehot = (iota_u == j[:, None]) & act[:, None]
         ohf = onehot.astype(inc.dtype)
-        hit = jnp.einsum("gku,gu->gk", inc, ohf) > 0.5
+        hit = jnp.einsum("gku,gu->gk", inc, ohf, precision=hi) > 0.5
         dying = ealive & hit
         dw = we * dying.astype(we.dtype)
         ben = ben - dw.sum(axis=1)
-        cand = jnp.where(onehot, jnp.inf,
-                         cand - jnp.einsum("gku,gk->gu", inc, dw))
+        cand = jnp.where(
+            onehot, jnp.inf,
+            cand - jnp.einsum("gku,gk->gu", inc, dw, precision=hi),
+        )
         totw = totw - (nodew * ohf).sum(axis=1)
         nal = nal - act.astype(jnp.int32)
         peel = peel.at[:, r].set(jnp.where(act, j, jnp.int32(-1)))

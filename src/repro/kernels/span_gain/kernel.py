@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import _compiler_params
 
 
 def _span_gain_kernel(codes_ref, rem_ref, out_ref):
@@ -60,7 +60,7 @@ def span_gain(
         ],
         out_specs=pl.BlockSpec((block_a, block_n), lambda ia, jn: (ia, jn)),
         out_shape=jax.ShapeDtypeStruct((a, n), jnp.int32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

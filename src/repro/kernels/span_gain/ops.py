@@ -9,9 +9,8 @@ bit-exact integer math, so the choice is purely a performance decision:
                   on small buckets where crossing into jax costs more than
                   the popcount itself.
   * "jax"       — jitted jnp popcount-reduce (XLA fuses the mask).
-  * "kernel"    — the Pallas kernel, compiled (TPU).
+  * "pallas"    — the Pallas kernel, compiled; raises off TPU.
   * "interpret" — the Pallas kernel in interpreter mode (CPU tests).
-  * "pallas"    — kernel on TPU, interpreter elsewhere.
 
 The query-batch axis is padded to the next power of two before any jax
 call: greedy rounds shrink the active set every iteration and one XLA
@@ -21,11 +20,12 @@ all-zero and sliced off.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 
-from .ref import span_gain_ref
+from .ref import span_gain_jnp, span_gain_ref
 
-_JNP_GAINS = None
+_JNP_GAINS = jax.jit(span_gain_jnp)
 
 
 def _pow2_pad(codes: np.ndarray, rem: np.ndarray, multiple: int = 1):
@@ -50,18 +50,18 @@ def span_gains(
     """Gain matrix (A, N) int64 for one greedy cover round."""
     if force == "numpy":
         return span_gain_ref(codes, rem)
-    import jax  # the caller's per-bucket dispatch guards importability
-
-    impl = force or ("kernel" if jax.default_backend() == "tpu" else "jax")
-    if impl == "pallas":
-        impl = "kernel" if jax.default_backend() == "tpu" else "interpret"
+    on_tpu = jax.default_backend() == "tpu"
+    impl = force or ("pallas" if on_tpu else "jax")
+    if impl == "pallas" and not on_tpu:
+        raise RuntimeError(
+            "span_gains: 'pallas' runs the compiled TPU kernel, but JAX's "
+            f"backend is {jax.default_backend()!r}; ask for 'interpret' to "
+            "run the kernel body in the interpreter"
+        )
+    if impl not in ("jax", "pallas", "interpret"):
+        raise ValueError(f"unknown span_gains backend {impl!r}")
     a = codes.shape[0]
     if impl == "jax":
-        global _JNP_GAINS
-        if _JNP_GAINS is None:
-            from .ref import span_gain_jnp
-
-            _JNP_GAINS = jax.jit(span_gain_jnp)
         codes, rem = _pow2_pad(codes, rem)
         c32 = np.ascontiguousarray(codes).view(np.uint32)   # (A2, N, W2)
         r32 = np.ascontiguousarray(rem).view(np.uint32)     # (A2, W2)

@@ -50,6 +50,7 @@ def main():
     from repro.checkpoint import CheckpointManager
     from repro.configs import get_config, reduce_config
     from repro.data import PlacementAwarePipeline
+    from repro.launch.mesh import make_debug_mesh
     from repro.launch.steps import make_train_step
     from repro.models import identity_dispatch, init_params
     from repro.optim.optimizers import make_optimizer
@@ -64,7 +65,7 @@ def main():
     mesh = None
     if args.mesh:
         d, m = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_debug_mesh(data=d, model=m)
         set_active_mesh(mesh)
 
     dispatch = None

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from .. import flags as _flags
@@ -92,10 +93,20 @@ def _shard_payloads(sharding: ShardingPlan, algorithm: str, seed: int,
 
 def _run_fits(payloads, workers: int):
     """(results aligned with payloads, used_pool) — pool when workers > 1
-    and a pool can be created, else the bit-identical serial path."""
+    and a pool can be created, else the bit-identical serial path.
+
+    A TPU belongs to one process, so on a TPU backend a pool of fit
+    processes cannot share it: workers > 1 raises there instead of
+    starting children that would fail or hang on the held chip."""
     live = [(i, p) for i, p in enumerate(payloads) if p is not None]
     results: list = [None] * len(payloads)
     if workers > 1 and len(live) > 1:
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"scale_workers={workers} would fork fit processes from a "
+                "process that holds the TPU; the chip serves one process, "
+                "so run sharded fits with scale_workers=1 on a TPU host"
+            )
         try:
             from concurrent.futures import ProcessPoolExecutor
 

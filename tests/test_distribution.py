@@ -22,9 +22,10 @@ from repro.launch.steps import (input_specs, make_serve_step, make_train_step,
 from repro.parallel import (batch_shardings, cache_shardings, param_shardings,
                             set_active_mesh)
 from repro.launch import roofline as rf
+from repro.launch.mesh import make_debug_mesh
 from repro.models import identity_dispatch, init_params, train_loss
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(data=2, model=4)
 set_active_mesh(mesh)
 
 # ---- 1. sharded train-step lower+compile for a dense arch
@@ -77,7 +78,7 @@ print("MARK moe_parity_ok")
 # ---- 4. elastic remesh: values survive a mesh change
 from repro.runtime import elastic_remesh
 set_active_mesh(None)
-mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+mesh_b = make_debug_mesh(data=4, model=2)
 state = {"blocks": {"wq": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
 moved = elastic_remesh(state, mesh_b)
 np.testing.assert_array_equal(np.asarray(moved["blocks"]["wq"]),
@@ -85,7 +86,7 @@ np.testing.assert_array_equal(np.asarray(moved["blocks"]["wq"]),
 print("MARK elastic_ok")
 
 # ---- 5. multi-pod mesh axes exist and shard the pod dimension
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = make_debug_mesh(data=2, model=2, pods=2)
 set_active_mesh(mesh3)
 pshard3 = param_shardings(param_struct(cfg), mesh3)
 specs3 = input_specs(cfg, ShapeConfig("t", 128, 8, "train"))

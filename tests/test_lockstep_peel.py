@@ -1,14 +1,15 @@
 """Backend equivalence for the lockstep_peel kernel package and the
 device-resident LMBR dispatch.
 
-Contract (tentpole of PR 6): every peel backend — the f64 numpy oracle, the
-jitted f32 jnp lockstep, the Pallas kernel in interpret mode — emits
-BIT-IDENTICAL trajectories on the integer-valued-weight domain the LMBR
-dispatcher enforces, and the full fit under ``lmbr_peel="device"|"pallas"``
-reproduces the vector engine's placement exactly (same members, same covers,
-same float-tie handling).  The flat engine also serves as fallback, so a
-device failure can never change results."""
+Contract: every peel backend — the f64 numpy oracle, the jitted f32 jnp
+lockstep, the Pallas kernel (compiled on TPU, interpreted elsewhere) —
+emits BIT-IDENTICAL trajectories on the integer-valued-weight domain the
+LMBR dispatcher enforces, and the full fit under
+``lmbr_peel="device"|"interpret"`` reproduces the vector engine's placement
+exactly (same members, same covers, same float-tie handling).  Off TPU,
+``"pallas"`` refuses to run instead of quietly interpreting."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -18,7 +19,7 @@ from repro.core.workloads import ispd_like_workload
 from repro.kernels.lockstep_peel.ops import lockstep_peel
 from repro.kernels.lockstep_peel.ref import lockstep_peel_ref
 
-jax = pytest.importorskip("jax")
+ON_TPU = jax.default_backend() == "tpu"
 
 
 # ------------------------------------------------------------- unit level
@@ -51,6 +52,10 @@ def test_backends_match_oracle(shape, force):
     rng = np.random.default_rng(G * 1000 + K * 10 + U)
     inc, we, nodew, nvalid = _rand_instance(rng, G, K, U)
     want = lockstep_peel_ref(inc, we, nodew, nvalid)
+    if force == "pallas" and not ON_TPU:
+        with pytest.raises(RuntimeError, match="compiled TPU kernel"):
+            lockstep_peel(inc, we, nodew, nvalid, force=force)
+        return
     got = lockstep_peel(inc, we, nodew, nvalid, force=force)
     for w, g, name in zip(want, got, ("peel", "rtot", "rben")):
         assert g.shape == w.shape, (force, name)
@@ -83,6 +88,10 @@ def _fit_members(wl, n, cap, max_moves, setups):
             pl = lmbr(wl.hypergraph, n, cap, seed=0, max_moves=max_moves)
         finally:
             flags.reset()
+        if setup["lmbr_peel"] in ("device", "pallas", "interpret"):
+            # a pinned dense peel ran, and the exactness gate skipped none
+            assert pl.stats["dense_peel_calls"] > 0, name
+            assert pl.stats["dense_peel_skips"] == 0, name
         members[name] = (pl.member.copy(), pl.stats["peel"])
     return members
 
@@ -90,7 +99,7 @@ def _fit_members(wl, n, cap, max_moves, setups):
 @pytest.mark.parametrize("tier", ["fig6", "fig9", "lmbr-stress"])
 def test_full_fit_backend_bit_identity(tier):
     """Placements (hence covers and every float tie-break) are identical
-    across vector / device / pallas peel backends and both cache
+    across vector / device / Pallas-kernel peel backends and both cache
     granularities on quick versions of the benchmark tiers."""
     if tier == "fig6":
         wl, n, cap, moves = random_workload(60, 200, seed=3), 6, 22.0, 24
@@ -105,7 +114,7 @@ def test_full_fit_backend_bit_identity(tier):
         "partition-epochs": dict(lmbr_peel="vector", lmbr_epochs="partition"),
     }
     if tier == "fig6":  # interpret-mode Pallas is slow; one tier covers it
-        setups["pallas"] = dict(lmbr_peel="pallas")
+        setups["pallas"] = dict(lmbr_peel="pallas" if ON_TPU else "interpret")
     members = _fit_members(wl, n, cap, moves, setups)
     want, _ = members["vector"]
     for name, (got, _) in members.items():
@@ -114,8 +123,8 @@ def test_full_fit_backend_bit_identity(tier):
 
 def test_device_peel_falls_back_on_float_weights():
     """Non-integer weights are outside the f32-exact domain: the dispatcher
-    must keep the flat engine (stats record the requested backend, results
-    stay bit-identical to vector)."""
+    must keep the flat engine and count every skipped batch (stats record
+    the requested backend, results stay bit-identical to vector)."""
     rng = np.random.default_rng(9)
     wl = random_workload(50, 150, seed=2)
     hg = wl.hypergraph
@@ -127,6 +136,9 @@ def test_device_peel_falls_back_on_float_weights():
         flags.reset()
     vec = lmbr(hg, 5, hg.total_node_weight() / 3, seed=0, max_moves=16)
     np.testing.assert_array_equal(dev.member, vec.member)
+    assert dev.stats["int_exact"] is False
+    assert dev.stats["dense_peel_calls"] == 0
+    assert dev.stats["dense_peel_skips"] > 0
 
 
 # ------------------------------------------------------------ flag surface
@@ -137,6 +149,8 @@ def test_device_peel_falls_back_on_float_weights():
     ("spanroundth12345", "span_round_threshold", 12345),
     ("peeldevice", "lmbr_peel", "device"),
     ("peelpallas", "lmbr_peel", "pallas"),
+    ("peelinterpret", "lmbr_peel", "interpret"),
+    ("spaninterpret", "span_backend", "interpret"),
     ("lmbrepochitem", "lmbr_epochs", "item"),
     ("lmbrepochpartition", "lmbr_epochs", "partition"),
 ])

@@ -237,6 +237,22 @@ def test_fit_sharded_serial_equals_pool(clustered_wl):
     serial.placement.validate()
 
 
+def test_fit_sharded_pool_refused_on_tpu(clustered_wl, monkeypatch):
+    """A TPU serves one process: on a TPU backend a process pool of shard
+    fits raises instead of starting children on a held chip, and the
+    serial path still runs."""
+    from repro.scale import parallel_fit
+
+    monkeypatch.setattr(parallel_fit.jax, "default_backend", lambda: "tpu")
+    hg = clustered_wl.hypergraph
+    with pytest.raises(RuntimeError, match="scale_workers=3"):
+        fit_sharded_placement(hg, 16, 110, num_shards=4, workers=3,
+                              seed=0, max_moves=40)
+    serial = fit_sharded_placement(hg, 16, 110, num_shards=4, workers=1,
+                                   seed=0, max_moves=40)
+    assert serial.stats["used_pool"] is False
+
+
 def test_fit_sharded_service_entry_point(clustered_wl):
     hg = clustered_wl.hypergraph
     svc = PlacementService("lmbr", seed=0)

@@ -126,7 +126,8 @@ def test_span_maintainer_tracks_mutations(seed):
     sm = SpanMaintainer(hg, pl)
     for _ in range(8):
         items = rng.choice(hg.num_nodes,
-                           size=int(rng.integers(1, 6)), replace=False)
+                           size=min(hg.num_nodes, int(rng.integers(1, 6))),
+                           replace=False)
         pl.member[int(rng.integers(0, pl.num_partitions)), items] = True
         sm.notify_items(items)
         want = batched_spans_csr(hg.edge_ptr, hg.edge_nodes, pl.member)

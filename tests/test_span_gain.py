@@ -3,8 +3,11 @@
 The gain matrix is integer popcount math, so every backend — numpy oracle,
 jitted jnp, Pallas kernel in interpret mode — must agree EXACTLY, including
 over the padding seams (query-batch pow2 pad, partition-axis 128 pad,
-uint64 -> uint32 lane split)."""
+uint64 -> uint32 lane split).  ``"pallas"`` is the compiled kernel: on a
+TPU it must match too, anywhere else it must refuse to run rather than
+quietly interpret."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -12,7 +15,7 @@ from repro import flags
 from repro.kernels.span_gain.ops import span_gains
 from repro.kernels.span_gain.ref import span_gain_ref
 
-jax = pytest.importorskip("jax")
+ON_TPU = jax.default_backend() == "tpu"
 
 # (A, N, W): odd batch sizes straddle the pow2 pad, N > 128 straddles the
 # lane pad, W > 1 exercises the multi-word reduce
@@ -31,6 +34,10 @@ def test_backends_match_oracle(shape, force):
     codes[0, 0, 0] = np.uint64(0xFFFFFFFFFFFFFFFF)
     rem[0, 0] = np.uint64(0xFFFFFFFFFFFFFFFF)
     want = span_gain_ref(codes, rem)
+    if force == "pallas" and not ON_TPU:
+        with pytest.raises(RuntimeError, match="compiled TPU kernel"):
+            span_gains(codes, rem, force=force)
+        return
     got = span_gains(codes, rem, force=force)
     assert got.dtype == np.int64
     np.testing.assert_array_equal(got, want)
@@ -68,9 +75,10 @@ def test_zero_rem_zero_gain():
 
 def test_engine_per_bucket_dispatch_is_exact():
     """batched_cover_csr under forced thresholds: all-numpy, all-accelerated
-    (threshold 0) and pinned-pallas must produce identical covers."""
+    (threshold 0) and the pinned kernel must produce identical covers, and
+    the per-backend gain-round counters must show the pinned backend ran."""
     from repro.core.hypergraph import Hypergraph
-    from repro.core.setcover import batched_cover_csr
+    from repro.core.setcover import ENGINE_COUNTERS, batched_cover_csr
 
     rng = np.random.default_rng(11)
     num_items, n_parts = 90, 6
@@ -92,16 +100,23 @@ def test_engine_per_bucket_dispatch_is_exact():
         want = run()
     finally:
         flags.reset()
-    for setup in (
-        dict(span_backend="auto", span_dispatch_threshold=0),
-        dict(span_backend="jax"),
-        dict(span_backend="pallas"),
+    kernel = "pallas" if ON_TPU else "interpret"
+    for setup, ran in (
+        (dict(span_backend="auto", span_dispatch_threshold=0),
+         "pallas" if ON_TPU else "jax"),
+        (dict(span_backend="jax"), "jax"),
+        (dict(span_backend=kernel), kernel),
     ):
         flags.FLAGS.update(setup)
+        before = dict(ENGINE_COUNTERS)
         try:
             got = run()
         finally:
             flags.reset()
+        key = f"{ran}_gain_rounds"
+        assert ENGINE_COUNTERS[key] > before[key], setup
+        assert ENGINE_COUNTERS["numpy_gain_rounds"] == \
+            before["numpy_gain_rounds"], setup
         for w, g in zip(want, got):
             np.testing.assert_array_equal(g, w, err_msg=str(setup))
 
