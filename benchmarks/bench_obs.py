@@ -164,9 +164,11 @@ def run(quick: bool = True) -> list[dict]:
 
     # "off" gate: time the disabled hook sequence itself (what
     # _route_microbatch pays when obs_level == "off" — one registry()
-    # accessor plus two .active checks) and bound it against the median
+    # accessor plus two .active checks, its serve.microbatch span and the
+    # span engine's cover.batch span) and bound it against the median
     # microbatch duration
     flags.FLAGS["obs_level"] = "off"
+    mb = int(flags.FLAGS["router_microbatch"])
     it = 200_000
     t_hook = np.inf
     for _ in range(3):
@@ -176,10 +178,15 @@ def run(quick: bool = True) -> list[dict]:
             reg = obs.registry()
             if reg.active:
                 pass
+            span = obs.tracer().span("serve.microbatch").begin()
+            tr = obs.tracer()
+            with tr.span("cover.batch", edges=mb) as sp:
+                if tr.active:
+                    sp.set()
             if reg.active:
                 pass
+            span.end()
         t_hook = min(t_hook, (time.perf_counter() - t0) / it)
-    mb = int(flags.FLAGS["router_microbatch"])
     mb_per_slice = -(-slice_q // mb)
     med_slice = float(np.median(base_slices))
     off_ratio = 1.0 + t_hook * mb_per_slice / max(med_slice, 1e-9)

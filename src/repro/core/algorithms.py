@@ -16,7 +16,6 @@ greedy set cover (replica selection).
 from __future__ import annotations
 
 import heapq
-import time
 from typing import Callable
 
 import numpy as np
@@ -733,7 +732,17 @@ class _LMBRState:
         re-evaluates cached free-space-independent peel trajectories under
         the live free space; "partition" restores the PR 5 per-partition
         epoch memo.  Both are exactness-neutral.
-        Returns {pair: (gain, items)} covering every requested pair."""
+        Returns {pair: (gain, items)} covering every requested pair.
+        Traced as one ``lmbr.gain`` span (args ``pairs``, ``cache_hits``)."""
+        st = self.stats
+        with _obs.tracer().span("lmbr.gain", pairs=len(pairs)) as sp:
+            hits = st["gain_cache_hits"] + st["gain_fp_hits"]
+            out = self._gains(pairs)
+            sp.set(cache_hits=st["gain_cache_hits"] + st["gain_fp_hits"]
+                   - hits)
+        return out
+
+    def _gains(self, pairs: list[tuple[int, int]]):
         self.stats["gain_calls"] += len(pairs)
         use_cache = _flags.FLAGS.get("lmbr_gain_cache", True)
         if _flags.FLAGS.get("lmbr_epochs", "item") == "item":
@@ -1366,8 +1375,7 @@ def lmbr(
     gain-cache setting (``flags.FLAGS["lmbr_gain_cache"]``).  The fitted
     ``Placement`` carries the move-engine counters in ``.stats`` (moves,
     gain_calls, gain_cache_hits, peel backend)."""
-    _tr = _obs.tracer()
-    _t0 = time.perf_counter() if _tr.active else 0.0
+    span = _obs.tracer().span("fit.lmbr", n=n).begin()
     energy_mask: np.ndarray | None = None
     if initial is not None:
         pl = Placement(
@@ -1414,7 +1422,8 @@ def lmbr(
         assign = hpa_mod.partition(hg, n, bal_cap, seed=seed, nruns=nruns)
         pl = _assign_to_placement(hg, assign, n, capacity)
     eng0 = engine_counters()
-    state = _LMBRState(hg, pl)
+    with _obs.tracer().span("lmbr.init"):
+        state = _LMBRState(hg, pl)
     if max_moves is None:
         max_moves = 50 * n
     if dest_mask is None:
@@ -1475,13 +1484,14 @@ def lmbr(
         # or dest and touching a moved item) — ONE batched engine call over
         # the ascending-id affected set; per-edge covers are independent, so
         # refresh order cannot influence results.
-        cand_arr = state.union_edges(src, dest)
-        if len(cand_arr):
-            ptr, nodes_ = hg.edges_csr(cand_arr)
-            hit = np.isin(nodes_, items)
-            ch = np.concatenate([[0], np.cumsum(hit)])
-            touches = ch[ptr[1:]] > ch[ptr[:-1]]
-            state.recompute_edges(cand_arr[touches])
+        with _obs.tracer().span("lmbr.refresh"):
+            cand_arr = state.union_edges(src, dest)
+            if len(cand_arr):
+                ptr, nodes_ = hg.edges_csr(cand_arr)
+                hit = np.isin(nodes_, items)
+                ch = np.concatenate([[0], np.cumsum(hit)])
+                touches = ch[ptr[1:]] > ch[ptr[:-1]]
+                state.recompute_edges(cand_arr[touches])
         # refresh PQ entries involving dest (Algorithm 4 lines 12-15)
         pairs: list[tuple[int, int]] = []
         for g in range(n):
@@ -1507,10 +1517,7 @@ def lmbr(
         # derivable as lmbr_gain_calls - hits
         for k in ("moves", "gain_calls", "gain_cache_hits", "gain_fp_hits"):
             reg.inc("lmbr_" + k, state.stats[k])
-    if _tr.active:
-        _tr.complete("fit.lmbr", _t0, time.perf_counter(), n=n,
-                     moves=state.stats["moves"],
-                     gain_calls=state.stats["gain_calls"])
+    span.end(moves=state.stats["moves"], gain_calls=state.stats["gain_calls"])
     return pl
 
 

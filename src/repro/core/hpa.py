@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
-import time
 from collections import OrderedDict
 
 import numpy as np
@@ -490,46 +489,40 @@ def partition(
         _PARTITION_CACHE.move_to_end(key)
         return cached.copy()
 
-    _tr = _obs.tracer()
-    _t0 = time.perf_counter() if _tr.active else 0.0
-    best_assign, best_cost = None, np.inf
-    for run in range(max(1, nruns)):
-        rng = np.random.default_rng(seed + 7919 * run)
-        # ---- coarsening phase
-        _tc = time.perf_counter() if _tr.active else 0.0
-        levels: list[tuple[Hypergraph, np.ndarray]] = []
-        cur = hg
-        # heterogeneous capacities coarsen against the tightest part: no
-        # cluster may exceed the smallest capacity (same semantics as the
-        # scalar bound); the scalar object passes through untouched
-        coarse_cap = float(np.min(capacity)) if het else capacity
-        while cur.num_nodes > coarsen_to:
-            coarse, cmap = _coarsen_once(cur, coarse_cap, rng)
-            if coarse.num_nodes >= 0.95 * cur.num_nodes:
-                break  # diminishing returns
-            levels.append((cur, cmap))
-            cur = coarse
-        if _tr.active:
-            _tr.complete("fit.hpa.coarsen", _tc, time.perf_counter(),
-                         run=run, levels=len(levels), coarse_n=cur.num_nodes)
-        # ---- initial partition on coarsest graph
-        _tc = time.perf_counter() if _tr.active else 0.0
-        assign = _initial_partition(cur, k, capacity, rng)
-        assign = _refine(cur, assign, k, capacity, rng, passes)
-        # ---- uncoarsen + refine
-        for fine, cmap in reversed(levels):
-            assign = assign[cmap]
-            assign = _refine(fine, assign, k, capacity, rng, passes)
-        assign = _fixup_capacity(hg, assign, k, capacity)
-        if _tr.active:
-            _tr.complete("fit.hpa.refine", _tc, time.perf_counter(), run=run)
-        cost = connectivity_cost(hg, assign, k)
-        if cost < best_cost:
-            best_cost, best_assign = cost, assign.copy()
-    _PARTITION_CACHE[key] = best_assign.copy()
-    if len(_PARTITION_CACHE) > _PARTITION_CACHE_MAX:
-        _PARTITION_CACHE.popitem(last=False)
-    if _tr.active:
-        _tr.complete("fit.hpa", _t0, time.perf_counter(), k=k,
-                     n=n, nruns=nruns)
+    tr = _obs.tracer()
+    with tr.span("fit.hpa", k=k, n=n, nruns=nruns):
+        best_assign, best_cost = None, np.inf
+        for run in range(max(1, nruns)):
+            rng = np.random.default_rng(seed + 7919 * run)
+            # ---- coarsening phase
+            with tr.span("fit.hpa.coarsen", run=run) as sp:
+                levels: list[tuple[Hypergraph, np.ndarray]] = []
+                cur = hg
+                # heterogeneous capacities coarsen against the tightest
+                # part: no cluster may exceed the smallest capacity (same
+                # semantics as the scalar bound); the scalar object passes
+                # through untouched
+                coarse_cap = float(np.min(capacity)) if het else capacity
+                while cur.num_nodes > coarsen_to:
+                    coarse, cmap = _coarsen_once(cur, coarse_cap, rng)
+                    if coarse.num_nodes >= 0.95 * cur.num_nodes:
+                        break  # diminishing returns
+                    levels.append((cur, cmap))
+                    cur = coarse
+                sp.set(levels=len(levels), coarse_n=cur.num_nodes)
+            with tr.span("fit.hpa.refine", run=run):
+                # ---- initial partition on coarsest graph
+                assign = _initial_partition(cur, k, capacity, rng)
+                assign = _refine(cur, assign, k, capacity, rng, passes)
+                # ---- uncoarsen + refine
+                for fine, cmap in reversed(levels):
+                    assign = assign[cmap]
+                    assign = _refine(fine, assign, k, capacity, rng, passes)
+                assign = _fixup_capacity(hg, assign, k, capacity)
+            cost = connectivity_cost(hg, assign, k)
+            if cost < best_cost:
+                best_cost, best_assign = cost, assign.copy()
+        _PARTITION_CACHE[key] = best_assign.copy()
+        if len(_PARTITION_CACHE) > _PARTITION_CACHE_MAX:
+            _PARTITION_CACHE.popitem(last=False)
     return best_assign

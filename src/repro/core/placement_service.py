@@ -248,9 +248,11 @@ class PlacementService:
             # algorithms swallow the kwarg
             algo_kwargs["node_cost"] = profile.access_cost
         with _obs.tracer().span("service.fit", algorithm=self.algorithm,
-                                n=num_partitions):
+                                n=num_partitions) as sp:
             pl = fn(hg, num_partitions, capacity, seed=self.seed,
                     nruns=self.nruns, **algo_kwargs)
+            st = pl.stats or {}
+            sp.set(moves=st.get("moves"), gain_calls=st.get("gain_calls"))
         pl.validate()
         self._apply_durability(
             pl, profile, num_partitions, capacity, durability_eps

@@ -347,12 +347,6 @@ def _round_loop_fn(B: int, N: int, W2: int, Rmax: int):
     fn = _ROUND_LOOPS.get(key)
     if fn is not None:
         return fn
-    reg = _obs.registry()
-    if reg.active:
-        # a compile-cache miss IS a jit retrace, keyed by batch-shape class
-        reg.inc("jit_retraces", shape=f"B{B}.N{N}.W{W2}.R{Rmax}")
-        _obs.tracer().event("jit.retrace", kernel="cover_round_loop",
-                            B=B, N=N, W=W2, Rmax=Rmax)
 
     def loop(codes, rem):  # codes (W2, B, N) uint32, rem (W2, B) uint32
         ch0 = jnp.full((Rmax, B), -1, dtype=jnp.int32)
@@ -602,7 +596,22 @@ def batched_cover_csr(
     same order, same lowest-id tie-break, ValueError on unplaced items), but
     one popcount matrix op per greedy round per size bucket instead of E
     Python loops.  Queries must be pin-deduplicated (Hypergraph CSR edges
-    always are)."""
+    always are).  Traced as one ``cover.batch`` span (args ``edges`` and
+    the call's ``host_rounds``, ``device_rounds``, ``accel_gain_rounds``)."""
+    tr = _obs.tracer()
+    with tr.span("cover.batch", edges=len(edge_ptr) - 1) as sp:
+        e0 = dict(ENGINE_COUNTERS) if tr.active else None
+        cov = _batched_cover(edge_ptr, edge_nodes, member, with_pin_parts)
+        if e0 is not None:
+            d = {k: ENGINE_COUNTERS[k] - e0[k] for k in e0}
+            sp.set(host_rounds=d["host_rounds"],
+                   device_rounds=d["device_rounds"],
+                   accel_gain_rounds=d["jax_gain_rounds"]
+                   + d["pallas_gain_rounds"] + d["interpret_gain_rounds"])
+    return cov
+
+
+def _batched_cover(edge_ptr, edge_nodes, member, with_pin_parts):
     edge_ptr = np.asarray(edge_ptr, dtype=np.int64)
     edge_nodes = np.asarray(edge_nodes, dtype=np.int64)
     E = len(edge_ptr) - 1

@@ -19,6 +19,13 @@ read state — and ``"off"`` must be timing-neutral on the serving loop.
 ``time.perf_counter()`` pairs: it always measures (``.seconds`` is valid
 at every obs level, so ``fit_seconds``-style stats keep their values) and
 additionally records a trace span when ``obs_level == "trace"``.
+
+At ``"trace"`` the tracer also records one ``jit.compile`` complete event
+per program XLA compiles or loads from the persistent cache (JAX's
+``backend_compile`` duration event; args ``program``), parented to the
+span open at the time.  The listener is registered with
+``jax.monitoring`` once, the first time ``tracer()`` returns the live
+tracer, and checks the level on every event.
 """
 
 from __future__ import annotations
@@ -63,10 +70,30 @@ def registry():
         else _REGISTRY
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener: list = []
+
+
+def _on_duration(event: str, duration: float, **kwargs):
+    if event != _COMPILE_EVENT:
+        return
+    tr = tracer()
+    if tr.active:
+        t1 = time.perf_counter()
+        tr.complete("jit.compile", t1 - duration, t1,
+                    program=str(kwargs.get("fun_name", "")))
+
+
 def tracer():
     """The live ``Tracer`` at "trace", else ``NULL_TRACER``."""
-    return _TRACER if _flags.FLAGS.get("obs_level", "off") == "trace" \
-        else NULL_TRACER
+    if _flags.FLAGS.get("obs_level", "off") != "trace":
+        return NULL_TRACER
+    if not _compile_listener:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listener.append(_on_duration)
+    return _TRACER
 
 
 def reset():
@@ -84,22 +111,19 @@ class timed:
     the same region shows up in the Chrome trace when enabled.
     """
 
-    __slots__ = ("name", "args", "t0", "seconds")
+    __slots__ = ("_span", "t0", "seconds")
 
     def __init__(self, name: str, **args):
-        self.name = name
-        self.args = args
+        self._span = tracer().span(name, **args)
         self.t0 = 0.0
         self.seconds = 0.0
 
     def __enter__(self):
+        self._span.begin()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
-        self.seconds = t1 - self.t0
-        tr = tracer()
-        if tr.active:
-            tr.complete(self.name, self.t0, t1, **self.args)
+        self.seconds = time.perf_counter() - self.t0
+        self._span.end()
         return False

@@ -6,19 +6,22 @@ module turns them back into structure after the run:
 * `load_events` — read a trace from JSONL (one event per line,
   ``Tracer.to_jsonl``) or the Chrome JSON object format
   (``{"traceEvents": [...]}``, ``Tracer.to_chrome_trace``).
-* `build_span_tree` — reconstruct the span tree from ts/dur containment
-  per tid (synchronous callers share tid 0, so nesting IS containment).
-  Spans that only *partially* overlap an open span — e.g. a
-  ``migration.transfer`` stamped at transfer start but landing several
-  microbatches later — are treated as parentless roots rather than
-  misattributed to whichever microbatch they happen to straddle.
+* `build_span_tree` — reconstruct the span tree.  Events that carry the
+  tracer's explicit ``args.id`` / ``args.parent`` are linked by those
+  ids.  Events without them (older traces) are linked by ts/dur
+  containment per tid (synchronous callers share tid 0, so nesting IS
+  containment); there, spans that only *partially* overlap an open
+  span — e.g. a ``migration.transfer`` stamped at transfer start but
+  landing several microbatches later — are treated as parentless roots
+  rather than misattributed to whichever microbatch they straddle.
 * `aggregate_spans` — per-span-name count / total / self / min / max /
   mean wall time, where self time is the span's duration minus its direct
   children's (clamped at 0; clock jitter can make children sum past the
   parent).
-* `critical_path` — from the named root (default ``fit.place``, the
-  fit's umbrella span in ``run_online``), repeatedly descend into the
-  longest child: the chain a latency optimisation has to shorten.
+* `critical_path` — from the named root (by default ``fit.place``, the
+  fit's umbrella span in ``run_online``, else ``service.fit``, the root
+  under ``PlacementService.fit``), repeatedly descend into the longest
+  child: the chain a latency optimisation has to shorten.
 * `top_slowest` — top-k slowest events of one name (default
   ``serve.microbatch``).
 * `render_report` — the plain-text run report ``tools/obs_report.py``
@@ -35,10 +38,10 @@ import json
 __all__ = [
     "load_events", "SpanNode", "build_span_tree", "aggregate_spans",
     "critical_path", "top_slowest", "render_report",
-    "FIT_ROOT_SPAN", "MICROBATCH_SPAN",
+    "FIT_ROOT_SPANS", "MICROBATCH_SPAN",
 ]
 
-FIT_ROOT_SPAN = "fit.place"
+FIT_ROOT_SPANS = ("fit.place", "service.fit")
 MICROBATCH_SPAN = "serve.microbatch"
 
 
@@ -98,15 +101,27 @@ class SpanNode:
 
 
 def build_span_tree(events: list) -> "list[SpanNode]":
-    """Reconstruct the span forest from ts/dur containment; returns the
-    roots in chronological order.  See the module docstring for how
+    """Reconstruct the span forest from explicit parent ids where events
+    carry them, else from ts/dur containment; returns the roots in
+    chronological order.  See the module docstring for how
     partially-overlapping spans are handled."""
     nodes = [SpanNode(e) for e in events if e.get("ph") == "X"]
+    by_id = {n.event["args"]["id"]: n for n in nodes
+             if "id" in n.event.get("args", {})}
+    roots: list[SpanNode] = []
     by_tid: dict = {}
     for node in nodes:
+        args = node.event.get("args", {})
+        if "id" in args:
+            parent = by_id.get(args.get("parent"))
+            if parent is None:
+                roots.append(node)
+            else:
+                node.parent = parent
+                parent.children.append(node)
+            continue
         key = (node.event.get("pid", 0), node.event.get("tid", 0))
         by_tid.setdefault(key, []).append(node)
-    roots: list[SpanNode] = []
     for group in by_tid.values():
         # parents first at equal ts: longer duration wins
         group.sort(key=lambda s: (s.ts, -s.dur))
@@ -159,12 +174,18 @@ def aggregate_spans(events: list) -> dict:
 
 
 def critical_path(events: list,
-                  root_name: str = FIT_ROOT_SPAN) -> "list[SpanNode]":
-    """The longest root span named ``root_name`` (any root if absent),
-    then its longest child, recursively — the chain to shorten first."""
+                  root_name: "str | None" = None) -> "list[SpanNode]":
+    """The longest root span named ``root_name`` (by default the first of
+    `FIT_ROOT_SPANS` any root carries; any root if none does), then its
+    longest child, recursively — the chain to shorten first."""
     roots = build_span_tree(events)
-    named = [r for r in roots if r.name == root_name]
-    pool = named if named else roots
+    names = FIT_ROOT_SPANS if root_name is None else (root_name,)
+    for name in names:
+        pool = [r for r in roots if r.name == name]
+        if pool:
+            break
+    else:
+        pool = roots
     if not pool:
         return []
     node = max(pool, key=lambda s: s.dur)

@@ -40,7 +40,6 @@ next router microbatch):
 
 from __future__ import annotations
 
-import time
 
 import numpy as np
 
@@ -312,43 +311,40 @@ class FailoverManager:
         an item that just received a copy), so the placements — order,
         destinations, float ties — are bit-identical to `repair_reference`.
         """
-        _tr = _obs.tracer()
-        _t0 = time.perf_counter() if _tr.active else 0.0
-        pl = self.pl
-        live_rows = np.ones(pl.num_partitions, dtype=bool)
-        live_rows[self.down_partitions] = False
-        order = self._repair_order(hg, k, items)
-        if not len(order):
-            return order
-        node_ptr, node_edges = hg.incidence()
-        repaired: list[int] = []
-        pos = 0
-        while pos < len(order):
-            # capped wave: on clustered workloads consecutive hot items
-            # often share edges, so a wave can end after one placement —
-            # the cap bounds the recompute waste to a constant factor
-            # instead of going quadratic over the remaining tail
-            wave = order[pos: pos + 64]
-            benefits = self._batched_benefits(hg, wave)
-            touched = np.zeros(hg.num_edges, dtype=bool)
-            i = 0
-            while i < len(wave):
-                v = int(wave[i])
-                ev = node_edges[node_ptr[v]: node_ptr[v + 1]]
-                if i > 0 and len(ev) and touched[ev].any():
-                    break  # precomputed benefit may be stale: new wave
-                if self._place_copies(hg, v, k, live_rows, benefits[i],
-                                      repaired):
-                    touched[ev] = True
-                i += 1
-            pos += max(i, 1)
-        self.stats["repaired_items"] += len(repaired)
-        reg = _obs.registry()
-        if reg.active:
-            reg.inc("failover_repaired_items_total", len(repaired))
-        if _tr.active:
-            _tr.complete("failover.repair", _t0, time.perf_counter(),
-                         copies=len(repaired))
+        with _obs.tracer().span("failover.repair") as span:
+            pl = self.pl
+            live_rows = np.ones(pl.num_partitions, dtype=bool)
+            live_rows[self.down_partitions] = False
+            order = self._repair_order(hg, k, items)
+            if not len(order):
+                return order
+            node_ptr, node_edges = hg.incidence()
+            repaired: list[int] = []
+            pos = 0
+            while pos < len(order):
+                # capped wave: on clustered workloads consecutive hot items
+                # often share edges, so a wave can end after one placement —
+                # the cap bounds the recompute waste to a constant factor
+                # instead of going quadratic over the remaining tail
+                wave = order[pos: pos + 64]
+                benefits = self._batched_benefits(hg, wave)
+                touched = np.zeros(hg.num_edges, dtype=bool)
+                i = 0
+                while i < len(wave):
+                    v = int(wave[i])
+                    ev = node_edges[node_ptr[v]: node_ptr[v + 1]]
+                    if i > 0 and len(ev) and touched[ev].any():
+                        break  # precomputed benefit may be stale: new wave
+                    if self._place_copies(hg, v, k, live_rows, benefits[i],
+                                          repaired):
+                        touched[ev] = True
+                    i += 1
+                pos += max(i, 1)
+            self.stats["repaired_items"] += len(repaired)
+            reg = _obs.registry()
+            if reg.active:
+                reg.inc("failover_repaired_items_total", len(repaired))
+            span.set(copies=len(repaired))
         return np.asarray(sorted(set(repaired)), dtype=np.int64)
 
     def repair_reference(self, hg: Hypergraph, k: int = 1,

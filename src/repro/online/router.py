@@ -259,6 +259,7 @@ class ReplicaRouter:
     def _route_microbatch(self, ptr, nodes, balance: bool) -> RoutedBatch:
         reg = _obs.registry()
         t0 = time.perf_counter() if reg.active else 0.0
+        span = _obs.tracer().span("serve.microbatch").begin()
         if balance:
             # rows ascending by (ledger load, id): the engine's lowest-row-id
             # tie-break becomes "least-loaded maximal-gain partition"
@@ -287,11 +288,8 @@ class ReplicaRouter:
             reg.inc("router_microbatches_total")
             # live reference: copied out lazily at snapshot time
             reg.gauge_vector("router_partition_load").set(self.load)
-            tr = _obs.tracer()
-            if tr.active:
-                tr.complete("serve.microbatch", t0, t1,
-                            queries=len(ptr) - 1,
-                            span_sum=int(cov.spans.sum()))
+            span.set(queries=len(ptr) - 1, span_sum=int(cov.spans.sum()))
+        span.end()
         return RoutedBatch(cov.spans, cov.cover_ptr, cover_parts, pin_parts,
                            ptr, nodes)
 

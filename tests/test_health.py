@@ -439,6 +439,40 @@ def test_aggregate_and_critical_path_and_top_slowest():
     assert critical_path([]) == []
 
 
+def _xid(name, ts, dur, sid, parent):
+    return _x(name, ts, dur, id=sid, parent=parent, fit=None)
+
+
+def test_span_tree_uses_explicit_parent_ids():
+    # containment would nest "child" under "transfer", which encloses it
+    # in time; their recorded parent is "root"
+    events = [
+        _xid("child", 10, 20, 2, 1),
+        _xid("transfer", 5, 40, 3, 1),
+        _xid("root", 0, 100, 1, None),
+        _xid("orphan", 200, 5, 4, 99),   # parent not in the trace: a root
+    ]
+    roots = build_span_tree(events)
+    assert [r.name for r in roots] == ["root", "orphan"]
+    assert [c.name for c in roots[0].children] == ["child", "transfer"]
+    assert all(c.parent is roots[0] for c in roots[0].children)
+
+
+def test_critical_path_roots_at_service_fit_without_fit_place():
+    events = [
+        _xid("service.fit", 0, 100, 1, None),
+        _xid("fit.lmbr", 1, 98, 2, 1),
+        _xid("fit.hpa", 2, 30, 3, 2),
+        _xid("lmbr.gain", 40, 50, 4, 2),
+        _xid("cover.batch", 300, 400, 5, None),
+    ]
+    path = critical_path(events)
+    assert [n.name for n in path] == ["service.fit", "fit.lmbr", "lmbr.gain"]
+    assert [n.name for n in critical_path(events, "cover.batch")] == [
+        "cover.batch"]
+    assert "critical path (service.fit)" in render_report(events)
+
+
 def test_load_events_jsonl_and_chrome_json_agree():
     flags.FLAGS["obs_level"] = "trace"
     obs.reset()

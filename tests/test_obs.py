@@ -13,6 +13,7 @@ from repro.core import (
     Hypergraph,
     PlacementService,
     Simulator,
+    hpa,
     random_workload,
 )
 from repro.obs import (
@@ -118,7 +119,8 @@ def test_span_nesting_by_containment():
     assert outer["ph"] == "X" and inner["ph"] == "X"
     assert outer["ts"] <= inner["ts"]
     assert outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"]
-    assert outer["args"] == {"k": 1}
+    assert outer["args"] == {"k": 1, "id": 1, "parent": None, "fit": None}
+    assert inner["args"] == {"id": 2, "parent": 1, "fit": None}
 
 
 def test_instant_and_counter_events():
@@ -164,6 +166,57 @@ def test_null_tracer_is_inert():
     assert json.loads(NULL_TRACER.to_chrome_trace()) == {"traceEvents": []}
 
 
+def test_begin_end_set_and_exception_unwinding():
+    tr = Tracer()
+    outer = tr.span("outer", a=1).begin()
+    with pytest.raises(RuntimeError):
+        with tr.span("mid"):
+            tr.span("left.open").begin()  # never ended: an exception
+            raise RuntimeError("boom")
+    tr.event("mark")
+    outer.set(b=2)
+    outer.end(c=3)
+    mid, mark, out = tr.events
+    assert mid["args"]["parent"] == out["args"]["id"]
+    assert mark["ph"] == "i" and mark["args"]["parent"] == out["args"]["id"]
+    assert {k: out["args"][k] for k in "abc"} == {"a": 1, "b": 2, "c": 3}
+    assert tr._stack == []  # the span left open ended with its parent
+    # the null span takes the same calls
+    sp = NULL_TRACER.span("x").begin()
+    sp.set(k=1)
+    sp.end(k=2)
+
+
+def test_fit_number_and_complete_parent():
+    tr = Tracer()
+    with tr.span("service.fit"):
+        with tr.span("fit.lmbr") as lm:
+            t_in = lm.t0 + 1e-9
+        with tr.span("service.refit"):  # nested request: same fit number
+            pass
+    with tr.span("service.fit") as second:
+        import time
+        tr.complete("late", second.t0 - 1.0, time.perf_counter())
+        tr.complete("inner", t_in + 1.0, time.perf_counter())
+    tr.complete("outside", 0.0, 1.0)
+    ev = {e["name"] + str(e["args"]["fit"]): e["args"] for e in tr.events}
+    assert ev["fit.lmbr1"]["fit"] == 1 and ev["service.refit1"]["fit"] == 1
+    assert ev["service.fit2"]["parent"] is None
+    # a complete event's parent is the innermost span open before its t0
+    assert ev["lateNone"]["parent"] is None
+    assert ev["inner2"]["parent"] == ev["service.fit2"]["id"]
+    assert ev["outsideNone"]["parent"] is None
+    ids = [e["args"]["id"] for e in tr.events]
+    assert len(set(ids)) == len(ids)
+
+
+def test_counter_events_carry_only_their_series():
+    tr = Tracer()
+    with tr.span("s"):
+        tr.counter("online", served=1)
+    assert tr.events[0]["args"] == {"served": 1}
+
+
 # --------------------------------------------------- level selection / flags
 def test_level_selection():
     assert obs.registry() is NULL_REGISTRY
@@ -198,7 +251,9 @@ def test_timed_always_measures_trace_only_when_tracing():
     with obs.timed("work", stage="x") as t:
         pass
     spans = obs.tracer().spans("work")
-    assert len(spans) == 1 and spans[0]["args"] == {"stage": "x"}
+    assert len(spans) == 1
+    assert spans[0]["args"] == {"stage": "x", "id": 1, "parent": None,
+                                "fit": None}
     assert t.seconds >= 0.0
 
 
@@ -213,16 +268,135 @@ def test_off_vs_trace_bit_identical_fit_and_serve():
 
     base = sim.run_online(wl.hypergraph, ALGORITHMS["lmbr"], name="lmbr",
                           seed=0, max_moves=40)
-    flags.FLAGS["obs_level"] = "trace"
-    obs.reset()
-    traced = sim.run_online(wl.hypergraph, ALGORITHMS["lmbr"], name="lmbr",
-                            seed=0, max_moves=40)
-    assert np.array_equal(base.spans, traced.spans)
-    assert np.array_equal(base.access_load, traced.access_load)
-    assert _summary_no_wall_clock(base) == _summary_no_wall_clock(traced)
+    base_plan = _small_fit()
+    for level in ("counters", "trace"):
+        flags.FLAGS["obs_level"] = level
+        obs.reset()
+        traced = sim.run_online(wl.hypergraph, ALGORITHMS["lmbr"],
+                                name="lmbr", seed=0, max_moves=40)
+        assert np.array_equal(base.spans, traced.spans)
+        assert np.array_equal(base.access_load, traced.access_load)
+        assert _summary_no_wall_clock(base) == _summary_no_wall_clock(traced)
+        plan = _small_fit()
+        assert np.array_equal(plan.member, base_plan.member)
+        assert plan.stats == base_plan.stats
     # and the traced run actually produced spans
     assert obs.tracer().spans("fit.lmbr")
     assert obs.tracer().spans("serve.microbatch")
+
+
+# ------------------------------------------------- the fit's span tree
+FIT_TREE = {
+    "service.fit": {"fit.lmbr"},
+    "fit.lmbr": {"fit.hpa", "lmbr.init", "lmbr.gain", "lmbr.refresh"},
+    "fit.hpa": {"fit.hpa.coarsen", "fit.hpa.refine"},
+    "lmbr.init": {"cover.batch"},
+    "lmbr.refresh": {"cover.batch"},
+}
+
+
+def _small_fit():
+    wl = random_workload(num_items=120, num_queries=300, density=5, seed=4)
+    qs = [wl.hypergraph.edge(e) for e in range(wl.hypergraph.num_edges)]
+    with hpa.fresh_partition_cache():
+        return PlacementService("lmbr", seed=0).fit(qs, 120, 8, 32)
+
+
+def _tree_edges(events):
+    """(parent name, child name) pairs of the tracer's events."""
+    by_id = {e["args"]["id"]: e for e in events}
+    return {(by_id[e["args"]["parent"]]["name"], e["name"])
+            for e in events if e["args"]["parent"] is not None}
+
+
+def test_fit_span_tree_and_parents_agree_with_containment():
+    flags.FLAGS["obs_level"] = "trace"
+    obs.reset()
+    plan = _small_fit()
+    ev = obs.tracer().spans()
+    by_id = {e["args"]["id"]: e for e in ev}
+    got: dict = {}
+    for a, b in _tree_edges(ev):
+        got.setdefault(a, set()).add(b)
+    assert got == FIT_TREE
+    (fit,) = obs.tracer().spans("service.fit")
+    assert fit["args"]["moves"] == plan.stats["moves"] > 0
+    assert fit["args"]["gain_calls"] == plan.stats["gain_calls"] > 0
+    for e in ev:
+        assert e["args"]["fit"] == 1
+        p = by_id.get(e["args"]["parent"])
+        if p is None:
+            assert e is fit
+            continue
+        assert p["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+    # the ids give the tree containment gives
+    stripped = [{**e, "args": {}} for e in ev]
+    assert obs.critical_path(stripped)[0].name == "service.fit"
+    assert ([n.name for n in obs.critical_path(ev)]
+            == [n.name for n in obs.critical_path(stripped)])
+    gain = obs.tracer().spans("lmbr.gain")
+    assert sum(g["args"]["pairs"] for g in gain) == plan.stats["gain_calls"]
+    assert (sum(g["args"]["cache_hits"] for g in gain)
+            == plan.stats["gain_cache_hits"] + plan.stats["gain_fp_hits"])
+    for c in obs.tracer().spans("cover.batch"):
+        assert c["args"]["edges"] > 0 and c["args"]["host_rounds"] > 0
+        assert c["args"]["accel_gain_rounds"] == 0  # CPU sizes stay numpy
+
+
+def test_spans_mirror_into_the_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    flags.FLAGS["obs_level"] = "trace"
+    obs.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        _small_fit()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    names = {e["name"] for e in obs.tracer().spans()}
+    host = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                host += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                         for ev in line.events if ev.name in names]
+    count = {n: sum(h[0] == n for h in host) for n in names}
+    assert count == {n: len(obs.tracer().spans(n)) for n in names}
+    # nesting by containment on the profiler's clock = the tracer's tree
+    host.sort(key=lambda h: (h[1], -h[2]))
+    edges, stack = set(), []
+    for name, s, e in host:
+        while stack and s >= stack[-1][2]:
+            stack.pop()
+        if stack:
+            edges.add((stack[-1][0], name))
+        stack.append((name, s, e))
+    assert edges == _tree_edges(obs.tracer().spans())
+
+
+def test_fresh_jit_inside_a_span_records_one_compile():
+    import jax
+
+    flags.FLAGS["obs_level"] = "trace"
+    obs.reset()
+    tr = obs.tracer()
+
+    def fresh_program(x):
+        return x * 3.0 + 17.25
+
+    with tr.span("outer"):
+        with tr.span("compiles") as sp:
+            jax.jit(fresh_program)(np.ones(7, np.float32))
+    compiles = tr.spans("jit.compile")
+    assert len(compiles) == 1
+    assert compiles[0]["args"]["parent"] == sp.id
+    assert "fresh_program" in compiles[0]["args"]["program"]
+    flags.FLAGS["obs_level"] = "counters"  # the listener checks the level
+    jax.jit(lambda x: x - 5.5)(np.ones(3, np.float32))
+    assert len(obs._TRACER.spans("jit.compile")) == 1
 
 
 # ------------------------------------------- end-to-end acceptance trace
