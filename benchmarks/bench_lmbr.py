@@ -164,18 +164,6 @@ def _tier_rows(tier, hg, n, capacity, max_moves, ref_budget=None):
         base, engine="vectorized-nocache", seconds=round(t_nc, 2),
         speedup=round(t_ref / max(t_nc, 1e-9), 1), cache_hits=0,
     ))
-    # size-dispatched hybrid: tiny peels -> reference, the rest batched
-    # (recovers the reference's edge on sparse near-span-1 tiers)
-    au_pl, t_au, _ = _time_engine(
-        hg, n, capacity, max_moves, initial, "peelauto"
-    )
-    if not (au_pl.member == vec_pl.member).all():
-        raise AssertionError(f"{tier}: peelauto changed the placement")
-    rows.append(dict(
-        base, engine="vectorized-auto", seconds=round(t_au, 2),
-        speedup=round(t_ref / max(t_au, 1e-9), 1),
-        cache_hits=(au_pl.stats or {}).get("gain_cache_hits"),
-    ))
     for r in rows:
         print(f"  {r}", flush=True)
     return rows

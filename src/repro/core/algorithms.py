@@ -441,10 +441,9 @@ class _LMBRState:
         self.edge_tick = np.zeros(E, dtype=np.int64)
         self.item_tick = np.zeros(hg.num_nodes, dtype=np.int64)
         self.tick = 0
-        sizes = np.diff(hg.edge_ptr)
-        self._esz_mean = float(sizes.mean()) if E else 0.0
-        # pairwise shared-edge counts for the "auto" peel dispatch: built on
-        # first use, then maintained by rank-k updates in recompute_edges
+        # pairwise shared-edge counts for the item cache's validity check:
+        # built on first use, then maintained by rank-k updates in
+        # recompute_edges
         self._shared_cnt: np.ndarray | None = None
         self._loads = pl.partition_weights()
         self._gain_cache: dict[tuple[int, int], tuple] = {}
@@ -550,22 +549,6 @@ class _LMBRState:
         the pair depends on moved, else return the memoized (gain, items)."""
         return self.max_gain_many([(src, dest)])[(src, dest)]
 
-    def _peel_width_bounds(self, pairs: list[tuple[int, int]]) -> np.ndarray:
-        """Per-pair degree-matrix width estimate for the ``lmbr_peel="auto"``
-        size dispatch: (shared-edge count) * (mean edge size).  The count
-        matrix is built once (edge-mask Gram product) and then maintained by
-        rank-k updates in `recompute_edges`, so each estimate is an O(1)
-        lookup — the dispatch signal never costs O(E) per pair.  The signal
-        only picks a backend; both backends are bit-identical."""
-        if self._shared_cnt is None:
-            m = self._edge_mask.astype(np.int64)
-            self._shared_cnt = m @ m.T
-        srcs = np.fromiter((s for s, _ in pairs), dtype=np.int64,
-                           count=len(pairs))
-        dests = np.fromiter((d for _, d in pairs), dtype=np.int64,
-                            count=len(pairs))
-        return self._shared_cnt[srcs, dests] * self._esz_mean
-
     # ----------------------------------------- item-granular gain cache
     def _shared_count(self, key: tuple[int, int]) -> int:
         """O(1) shared-edge count off the maintained Gram matrix."""
@@ -638,13 +621,13 @@ class _LMBRState:
         """Peel projected pairs, returning {key: (pool, fp, traj)}.  The
         dense device backends only engage on the integer-exact domain; a
         requested device peel outside it is counted in ``dense_peel_skips``
-        and runs the flat numpy lockstep with trajectory recording, as every
-        other backend does."""
+        and runs the host's per-pair trajectory peel
+        (``_lmbr_peel_scalar``), as every other backend does."""
         if backend in _DENSE_PEELS:
             if self._int_exact:
                 return _lmbr_peel_dense(self, proj, backend)
             self.stats["dense_peel_skips"] += 1
-        return _lmbr_peel_flat(self, proj, collect_traj=True)
+        return _lmbr_peel_scalar(self, proj)
 
     def _max_gain_many_item(self, pairs, use_cache: bool):
         out: dict[tuple[int, int], tuple] = {}
@@ -667,11 +650,6 @@ class _LMBRState:
         backend = _flags.FLAGS.get("lmbr_peel", "vector")
         if backend == "reference":
             ref_keys, rest = misses, []
-        elif backend == "auto":
-            thresh = int(_flags.FLAGS.get("lmbr_peel_threshold", 256))
-            bounds = self._peel_width_bounds(misses)
-            ref_keys = [k for k, b in zip(misses, bounds) if b < thresh]
-            rest = [k for k, b in zip(misses, bounds) if b >= thresh]
         else:
             ref_keys, rest = [], misses
         for k in ref_keys:
@@ -719,12 +697,9 @@ class _LMBRState:
 
     def max_gain_many(self, pairs: list[tuple[int, int]]):
         """Epoch-cached batch gain evaluation.  Cache hits are answered from
-        the memo; the misses run through ONE lockstep batched peel (or the
-        pure-Python oracle pair-by-pair under ``lmbr_peel="reference"``;
-        ``"auto"`` routes pairs whose degree-matrix width estimate is below
-        ``flags.FLAGS["lmbr_peel_threshold"]`` to the oracle — on sparse
-        near-span-1 workloads tiny peels beat the batch-array assembly —
-        and batches the rest; all backends are bit-identical).
+        the memo; the misses are projected in one batch and peeled pair by
+        pair with trajectories (or run through the pure-Python oracle under
+        ``lmbr_peel="reference"``; all backends are bit-identical).
 
         Cache granularity follows ``flags.lmbr_epochs``: "item" (default)
         runs the two-level item-granular cache — per-pair epoch stamps plus
@@ -767,16 +742,6 @@ class _LMBRState:
                 computed = {
                     k: _lmbr_max_gain_reference(self, *k) for k in misses
                 }
-            elif backend == "auto":
-                thresh = int(_flags.FLAGS.get("lmbr_peel_threshold", 256))
-                bounds = self._peel_width_bounds(misses)
-                computed = {
-                    k: _lmbr_max_gain_reference(self, *k)
-                    for k, b in zip(misses, bounds) if b < thresh
-                }
-                big = [k for k, b in zip(misses, bounds) if b >= thresh]
-                if big:
-                    computed.update(_lmbr_gain_batch(self, big))
             else:
                 computed = _lmbr_gain_batch(self, misses)
             if use_cache:
@@ -841,9 +806,13 @@ def _lmbr_max_gain_reference(state: _LMBRState, src: int, dest: int):
             deg[v] += we
     alive_nodes = set(inc)
     alive_edge = [True] * len(proj)
-    # accumulate in inc insertion order (first-encounter over the ascending
-    # shared-edge scan) — never in set iteration order
-    total_w = sum(float(node_w[v]) for v in inc)
+    # left fold in inc insertion order (first-encounter over the ascending
+    # shared-edge scan) — never in set iteration order, and never sum(),
+    # which compensates float additions (Python 3.12+) where the engines
+    # fold plainly
+    total_w = 0.0
+    for v in inc:
+        total_w += float(node_w[v])
     heap = [(d, v) for v, d in deg.items()]
     heapq.heapify(heap)
     best_gain, best_items = 0.0, None
@@ -1069,8 +1038,8 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
     every round on device via ``repro.kernels.lockstep_peel``.  Only the
     free-space-independent trajectories come back; selection happens in
     ``_eval_traj``.  Caller guarantees the integer-exact weight domain, so
-    the f32 trajectories are bit-identical to the flat f64 engine's.
-    Returns {key: (pool, fp, traj)} like ``_lmbr_peel_flat``."""
+    the f32 trajectories are bit-identical to the host f64 peel's.
+    Returns {key: (pool, fp, traj)} like ``_lmbr_peel_scalar``."""
     force = _DENSE_PEELS[backend]
     node_w = state.hg.node_weights
     out: dict[tuple[int, int], tuple] = {}
@@ -1081,7 +1050,7 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
         k2 = 1 << max(2, (len(p[2]) - 1).bit_length())
         # a single pathological pair can dwarf the batch; densifying it
         # would blow memory (and a side past 2^12 makes a kernel whose
-        # compile runs for minutes), so it keeps the flat CSR engine
+        # compile runs for minutes), so it peels on the host
         if u2 * k2 > 1 << 22 or max(u2, k2) > 1 << 12:
             huge.append(p)
         else:
@@ -1113,12 +1082,80 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
                 )
                 out[p[0]] = (p[3], (p[2], p[3], p[4], p[5], p[6]), traj)
     if huge:
-        out.update(_lmbr_peel_flat(state, huge, collect_traj=True))
+        out.update(_lmbr_peel_scalar(state, huge))
     return out
 
 
-def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple],
-                    collect_traj: bool = False):
+def _lmbr_peel_scalar(state: _LMBRState, proj: list[tuple]):
+    """Per-pair heap peel with trajectories over projected pairs: Python
+    lists and a heap of (degree, column), one pair at a time.
+
+    Returns {key: (pool, fp, traj)}: the free-space-independent
+    head-of-round states (peeled column, weight, benefit) that
+    ``_eval_traj`` and the trajectory cache select from.  It makes the
+    float operations of ``_lmbr_peel_flat`` and of the oracle in the same
+    order, so a selection over its trajectory is bit-identical to theirs.
+    Degrees are left folds over the pins in scan order (edges ascending,
+    pins in edge order); each round
+    records the head-of-round weight and benefit, peels the lowest degree
+    (ties -> lowest column, as ``argmin``), and retires the item's alive
+    edges in ascending order, each first from the benefit and then from
+    its alive pins' degrees.  A heap entry is live only while its degree
+    equals the column's exactly, so no tolerance can reorder a near-tie."""
+    node_w = state.hg.node_weights
+    heappop, heappush = heapq.heappop, heapq.heappush
+    out: dict[tuple[int, int], tuple] = {}
+    for p in proj:
+        key, _, we_a, uniq, loc_a, cedge_a, pin_cnt, totw = p
+        we = we_a.tolist()
+        nw = node_w[uniq].tolist()
+        n_alive = len(nw)
+        deg = [0.0] * n_alive
+        inc: list[list[int]] = [[] for _ in range(n_alive)]
+        pins: list[list[int]] = [[] for _ in we]
+        for c, e in zip(loc_a.tolist(), cedge_a.tolist()):
+            deg[c] += we[e]
+            inc[c].append(e)
+            pins[e].append(c)
+        benefit = we[0]
+        for w in we[1:]:
+            benefit += w
+        heap = [(d, c) for c, d in enumerate(deg)]
+        heapq.heapify(heap)
+        alive = [True] * n_alive
+        edge_alive = [True] * len(we)
+        rj: list[int] = []
+        rtot: list[float] = []
+        rben: list[float] = []
+        while benefit > 1e-12 and n_alive:
+            rtot.append(totw)
+            rben.append(benefit)
+            d, j = heappop(heap)
+            while not (alive[j] and d == deg[j]):
+                d, j = heappop(heap)
+            rj.append(j)
+            alive[j] = False
+            n_alive -= 1
+            totw -= nw[j]
+            for e in inc[j]:
+                if edge_alive[e]:
+                    edge_alive[e] = False
+                    w = we[e]
+                    benefit -= w
+                    for u in pins[e]:
+                        if alive[u]:
+                            deg[u] -= w
+                            heappush(heap, (deg[u], u))
+        traj = (
+            (np.array(rj, dtype=np.int64), np.array(rtot, dtype=np.float64),
+             np.array(rben, dtype=np.float64))
+            if rj else None
+        )
+        out[key] = (uniq, (we_a, uniq, loc_a, cedge_a, pin_cnt), traj)
+    return out
+
+
+def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple]):
     """Flat lockstep peel over projected pairs.
 
     Peel (all pairs in lockstep): pair-local items live in dense (G, Umax)
@@ -1134,10 +1171,7 @@ def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple],
     lockstep execution cannot perturb results — same subsets, same gain
     floats, even under adversarial near-ties.
 
-    Returns {key: (gain, items)} by default (best state tracked in-loop);
-    with ``collect_traj`` the head-of-round states are recorded instead and
-    the return is {key: (pool, fp, traj)} for ``_eval_traj`` / the
-    trajectory cache — same rounds, same floats, one selection rule."""
+    Returns {key: (gain, items)}, the best state tracked in-loop."""
     hg = state.hg
     node_w = hg.node_weights
     out: dict[tuple[int, int], tuple] = {}
@@ -1219,35 +1253,22 @@ def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple],
     has_best = np.zeros(G, dtype=bool)
 
     # ---- lockstep weighted peel (getKDensestNodes, Asahiro-style greedy)
-    rec_rows: list[np.ndarray] = []
-    rec_j: list[np.ndarray] = []
-    rec_tot: list[np.ndarray] = []
-    rec_ben: list[np.ndarray] = []
     act = np.flatnonzero((benefit > 1e-12) & (n_alive > 0))
     while len(act):
         t = totw[act]
-        if collect_traj:
-            # head-of-round snapshot (the fancy-index gathers are already
-            # fresh arrays); selection is deferred to _eval_traj
-            rec_rows.append(act)
-            rec_tot.append(t)
-            rec_ben.append(benefit[act])
-        else:
-            # record states that fit the destination's free space
-            fits = t <= c_arr[act] + 1e-12
-            if fits.any():
-                rows = act[fits]
-                g = benefit[rows] / np.maximum(t[fits], 1e-12)
-                imp = g > best_gain[rows]
-                if imp.any():
-                    r2 = rows[imp]
-                    best_gain[r2] = g[imp]
-                    best_set[r2] = alive[r2]
-                    has_best[r2] = True
+        # record states that fit the destination's free space
+        fits = t <= c_arr[act] + 1e-12
+        if fits.any():
+            rows = act[fits]
+            g = benefit[rows] / np.maximum(t[fits], 1e-12)
+            imp = g > best_gain[rows]
+            if imp.any():
+                r2 = rows[imp]
+                best_gain[r2] = g[imp]
+                best_set[r2] = alive[r2]
+                has_best[r2] = True
         # peel each active pair's lowest-degree item (ties -> lowest id)
         j = np.argmin(cand[act], axis=1)
-        if collect_traj:
-            rec_j.append(j)
         alive[act, j] = False
         cand[act, j] = np.inf
         n_alive[act] -= 1
@@ -1280,35 +1301,11 @@ def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple],
             np.add.at(cand, (rows_t[lv], cols[lv]), -wrep[lv])
         act = act[(benefit[act] > 1e-12) & (n_alive[act] > 0)]
 
-    if not collect_traj:
-        for i, p in enumerate(proj):
-            if has_best[i]:
-                out[p[0]] = (float(best_gain[i]), p[3][best_set[i, : U[i]]])
-            else:
-                out[p[0]] = (0.0, None)
-        return out
-
-    # ---- group the recorded rounds back into per-pair trajectories
-    # (stable sort by pair keeps round order within each pair)
-    rows_all = (np.concatenate(rec_rows) if rec_rows
-                else np.zeros(0, dtype=np.int64))
-    j_all = (np.concatenate(rec_j) if rec_j
-             else np.zeros(0, dtype=np.int64))
-    tot_all = (np.concatenate(rec_tot) if rec_tot
-               else np.zeros(0, dtype=np.float64))
-    ben_all = (np.concatenate(rec_ben) if rec_ben
-               else np.zeros(0, dtype=np.float64))
-    order = np.argsort(rows_all, kind="stable")
-    counts = np.bincount(rows_all, minlength=G)
-    ptr = np.zeros(G + 1, dtype=np.int64)
-    np.cumsum(counts, out=ptr[1:])
     for i, p in enumerate(proj):
-        sl = order[ptr[i]: ptr[i + 1]]
-        traj = (
-            (j_all[sl].astype(np.int64), tot_all[sl], ben_all[sl])
-            if len(sl) else None
-        )
-        out[p[0]] = (p[3], (p[2], p[3], p[4], p[5], p[6]), traj)
+        if has_best[i]:
+            out[p[0]] = (float(best_gain[i]), p[3][best_set[i, : U[i]]])
+        else:
+            out[p[0]] = (0.0, None)
     return out
 
 

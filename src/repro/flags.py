@@ -59,9 +59,11 @@ FLAGS = {
     # than the per-round threshold; small refresh buckets (a few edges
     # after an LMBR move) stay on numpy.
     "span_round_threshold": 200_000,
-    # LMBR Algorithm-5 peel backend.  "vector" (default) runs the batched
-    # CSR peel (flat pin-attribution projection + scatter-add degree
-    # updates); "reference" the retained pure-Python oracle; "device" the
+    # LMBR Algorithm-5 peel backend.  "vector" (default) projects the
+    # missed pairs in one batch (flat pin-attribution projection) and peels
+    # each pair's trajectory on the host with a heap (the lockstep numpy
+    # peel under lmbr_epochs="partition"); "reference" the retained
+    # pure-Python oracle; "device" the
     # jitted dense lockstep peel (repro.kernels.lockstep_peel, jnp path);
     # "pallas" the compiled Pallas lockstep-peel kernel (raises off TPU);
     # "interpret" the same kernel in the Pallas interpreter (CPU tests).
@@ -93,14 +95,6 @@ FLAGS = {
     # (cover/pin-attribution epoch of either side, membership epoch of the
     # destination).  Exactness-neutral; off reproduces the uncached engine.
     "lmbr_gain_cache": True,
-    # hybrid-peel crossover for ``lmbr_peel="auto"``: a candidate (src, dest)
-    # pair whose degree-matrix width estimate (shared-edge count * mean edge
-    # size, an O(1) lookup off the maintained pair-count matrix) is below
-    # this runs the pure-Python reference peel — on sparse near-span-1
-    # workloads (fig9 circuits) tiny peels beat the batch-array assembly.
-    # Both backends are bit-identical, so this is a pure perf knob;
-    # calibrated by benchmarks/bench_lmbr.py's vectorized-auto rows.
-    "lmbr_peel_threshold": 256,
     # online router: queries per batched_cover_csr call in the streaming
     # replica-selection router (repro.online.ReplicaRouter).  Calibrated by
     # benchmarks/bench_online.py's router sweep: big enough to amortize the
@@ -260,12 +254,10 @@ def set_variant(spec: str):
             if backend not in ("auto", "numpy", "device"):
                 raise ValueError(f"unknown span round backend {backend!r}")
             FLAGS["span_round_backend"] = backend
-        elif part.startswith("peelth"):
-            FLAGS["lmbr_peel_threshold"] = int(part[len("peelth"):])
         elif part.startswith("peel"):
             backend = part[len("peel"):]
-            if backend not in ("vector", "reference", "auto", "device",
-                               "pallas", "interpret"):
+            if backend not in ("vector", "reference", "device", "pallas",
+                               "interpret"):
                 raise ValueError(f"unknown lmbr peel backend {backend!r}")
             FLAGS["lmbr_peel"] = backend
         elif part.startswith("lmbrepoch"):
@@ -388,7 +380,7 @@ def reset():
                  span_dispatch_threshold=48_000, span_round_backend="auto",
                  span_round_threshold=200_000, lmbr_peel="vector",
                  lmbr_epochs="item",
-                 lmbr_gain_cache=True, lmbr_peel_threshold=256,
+                 lmbr_gain_cache=True,
                  router_microbatch=384, router_balance=False,
                  drift_window=512, drift_threshold=1.25,
                  router_ledger_epsilon=0.0, scale_shards=0, scale_workers=1,

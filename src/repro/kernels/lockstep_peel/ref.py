@@ -22,7 +22,7 @@ representable exactly in float32, sums are exact under ANY association
 order, and the f32 device trajectory equals the f64 host trajectory
 bit-for-bit after the (exact) widening cast.
 
-Round semantics (mirrors ``algorithms._lmbr_peel_flat`` / the pure-Python
+Round semantics (mirrors ``algorithms._lmbr_peel_scalar`` / the pure-Python
 oracle): a pair is active while its alive-edge benefit is positive and
 items remain; each active round records (totw, benefit) at the round head,
 peels the lowest-degree item (ties -> lowest slot id = lowest item id,

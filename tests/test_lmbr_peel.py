@@ -15,9 +15,13 @@ from repro import flags
 from repro.core import hpa_partition, lmbr, random_workload
 from repro.core.algorithms import (
     _assign_to_placement,
+    _eval_traj,
     _lmbr_gain_batch,
     _lmbr_max_gain_reference,
     _lmbr_max_gain_vectorized,
+    _lmbr_peel_flat,
+    _lmbr_peel_scalar,
+    _lmbr_project,
     _LMBRState,
 )
 from repro.core.hypergraph import Hypergraph
@@ -74,6 +78,72 @@ def test_peel_matches_oracle_randomized(weighted_nodes, weighted_edges):
         for key in pairs:
             ref = _lmbr_max_gain_reference(state, *key)
             _assert_pair_equal(ref, batch[key], (trial, key))
+
+
+@pytest.mark.parametrize("batch", ["one", "all"])
+@pytest.mark.parametrize("weighted_nodes,weighted_edges", [
+    (False, False), (True, False), (False, True), (True, True),
+])
+def test_scalar_peel_matches_lockstep_bytes(weighted_nodes, weighted_edges,
+                                            batch):
+    """The scalar heap peel's trajectories, selected under any free space,
+    give the lockstep peel's exact (gain, items), pair by pair and over all
+    n(n-1) pairs at once; its pool and fingerprint are the projection's."""
+    rng = np.random.default_rng(11 + 2 * weighted_nodes + weighted_edges)
+    for trial in range(4):
+        hg, state = _random_state(
+            rng, weighted_nodes=weighted_nodes, weighted_edges=weighted_edges,
+        )
+        n = state.pl.num_partitions
+        pairs = [(s, d) for s in range(n) for d in range(n) if s != d]
+        _, proj = _lmbr_project(state, pairs)
+        assert proj
+        batches = [[p] for p in proj] if batch == "one" else [proj]
+        for b in batches:
+            scalar = _lmbr_peel_scalar(state, b)
+            assert scalar.keys() == {p[0] for p in b}
+            for p in b:
+                pool, fp, _ = scalar[p[0]]
+                np.testing.assert_array_equal(pool, p[3])
+                for x, y in zip(fp, (p[2], p[3], p[4], p[5], p[6])):
+                    np.testing.assert_array_equal(x, y)
+            for c in ("own", 0.5, 0.25, 1e18):
+                bc = [p if c == "own" else
+                      (p[0], p[1] * c if c < 1 else c, *p[2:]) for p in b]
+                flat = _lmbr_peel_flat(state, bc)
+                for p in bc:
+                    pool, _, traj = scalar[p[0]]
+                    _assert_pair_equal(flat[p[0]],
+                                       _eval_traj(pool, traj, p[1]),
+                                       (trial, batch, c, p[0]))
+
+
+def test_host_peel_paths_give_the_same_fit():
+    """A float-weighted fit is the same plan, with the same gain calls and
+    moves, whether its peels run as the scalar trajectory peel behind the
+    item cache (the default), as the lockstep peel behind the partition
+    epoch cache, or as the pure-Python oracle."""
+    wl = random_workload(num_items=120, num_queries=260, density=5, seed=2)
+    rng = np.random.default_rng(4)
+    hg = wl.hypergraph
+    hg = Hypergraph(hg.edge_ptr, hg.edge_nodes,
+                    rng.uniform(0.5, 1.5, hg.num_nodes),
+                    rng.uniform(0.1, 3.0, hg.num_edges))
+    fits = {}
+    for spec in ("baseline", "lmbrepochpartition", "peelreference"):
+        flags.set_variant(spec)
+        try:
+            fits[spec] = lmbr(hg, 9, 25, seed=0)
+        finally:
+            flags.reset()
+    sc = fits["baseline"]
+    assert not sc.stats["int_exact"]
+    assert sc.stats["peel_pairs"] > 0
+    for spec in ("lmbrepochpartition", "peelreference"):
+        other = fits[spec]
+        np.testing.assert_array_equal(sc.member, other.member, err_msg=spec)
+        for k in ("gain_calls", "moves"):
+            assert sc.stats[k] == other.stats[k], (spec, k)
 
 
 def test_peel_zero_capacity_dest():
@@ -207,24 +277,6 @@ def test_lmbr_warm_start_unchanged():
     assert (out.member[pl0.member]).all()
 
 
-def test_peelauto_bit_identical_and_mixed_dispatch():
-    """The size-dispatched hybrid peel (`peelauto`) routes small pairs to the
-    reference and large ones to the batch — and is bit-identical to the pure
-    vectorized engine either way."""
-    wl = random_workload(num_items=120, num_queries=260, density=5, seed=2)
-    hg = wl.hypergraph
-    vec = lmbr(hg, 9, 25, seed=0)
-    for spec in ("peelauto", "peelauto+peelth1", "peelauto+peelth100000"):
-        flags.set_variant(spec)
-        try:
-            auto = lmbr(hg, 9, 25, seed=0)
-        finally:
-            flags.reset()
-        np.testing.assert_array_equal(vec.member, auto.member)
-        assert auto.stats["peel"] == "auto"
-        assert auto.stats["moves"] == vec.stats["moves"]
-
-
 def test_variant_validation_errors():
     """set_variant rejects unknown backends/components instead of silently
     accepting them."""
@@ -237,12 +289,23 @@ def test_variant_validation_errors():
         flags.reset()
 
 
+@pytest.mark.parametrize("spec", ["peelauto", "peelth64"])
+def test_retired_peel_variants_rejected(spec):
+    """The size-dispatched hybrid peel and its threshold are gone: their
+    variant strings fail loudly instead of being ignored."""
+    try:
+        with pytest.raises(ValueError):
+            flags.set_variant(spec)
+        assert flags.FLAGS["lmbr_peel"] == "vector"
+    finally:
+        flags.reset()
+
+
 def test_variant_roundtrip_online_knobs():
     try:
-        flags.set_variant("peelauto+peelth64+routerbal1+routermb512"
+        flags.set_variant("peelreference+routerbal1+routermb512"
                           "+driftw256+driftth1.5")
-        assert flags.FLAGS["lmbr_peel"] == "auto"
-        assert flags.FLAGS["lmbr_peel_threshold"] == 64
+        assert flags.FLAGS["lmbr_peel"] == "reference"
         assert flags.FLAGS["router_balance"] is True
         assert flags.FLAGS["router_microbatch"] == 512
         assert flags.FLAGS["drift_window"] == 256

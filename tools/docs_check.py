@@ -59,10 +59,10 @@ NON_FLAGS = {"span_gain", "span_gain_calibration", "span_gain_ref",
              "health_alerts_active"}
 # backticked tokens that should parse as --variant specs
 VARIANT_RE = re.compile(
-    r"^(baseline|mla_decomp|sp2?|accum\d+|cf[\d.]+|spanth\d+|peelth\d+|"
+    r"^(baseline|mla_decomp|sp2?|accum\d+|cf[\d.]+|spanth\d+|"
     r"span(auto|numpy|jax|pallas|interpret)|spanroundth\d+|"
     r"spanround(auto|numpy|device)|"
-    r"peel(vector|reference|auto|device|pallas|interpret)|"
+    r"peel(vector|reference|device|pallas|interpret)|"
     r"lmbrcache[01]|lmbrepoch(item|partition)|"
     r"routerbal[01]|routermb\d+|routereps[\d.]+|"
     r"driftw\d+|driftth[\d.]+|shards\d+|scalew\d+|brepair\d+|"
