@@ -46,6 +46,7 @@ from .setcover import (  # noqa: F401
 from .hpa import partition as hpa_partition  # noqa: F401
 from .algorithms import (  # noqa: F401
     ALGORITHMS,
+    WEIGHTED_EDGES,
     ds,
     hpa_placement,
     ihpa,

@@ -458,9 +458,12 @@ class _LMBRState:
             and (nw.size == 0
                  or (np.all(nw == np.rint(nw)) and float(nw.sum()) < 2 ** 24))
         )
+        # peel_pins_max: the widest peel batch's costly pins per round,
+        # sum(pins) / Umax (a lockstep round peels one item of every pair)
         self.stats = dict(gain_calls=0, gain_cache_hits=0, gain_fp_hits=0,
-                          peel_pairs=0, moves=0, dense_peel_calls=0,
-                          dense_peel_skips=0, int_exact=self._int_exact)
+                          peel_pairs=0, peel_pins_max=0.0, moves=0,
+                          dense_peel_calls=0, dense_peel_skips=0,
+                          int_exact=self._int_exact)
 
     @property
     def part_edges(self) -> list[set[int]]:
@@ -682,6 +685,10 @@ class _LMBRState:
                 peel_list.append(p)
             if peel_list:
                 self.stats["peel_pairs"] += len(peel_list)
+                self.stats["peel_pins_max"] = max(
+                    self.stats["peel_pins_max"],
+                    sum(len(p[4]) for p in peel_list)
+                    / max(len(p[3]) for p in peel_list))
                 reg = _obs.registry()
                 if reg.active:
                     reg.inc("lmbr_peel_rounds")
@@ -1514,7 +1521,8 @@ def lmbr(
         # derivable as lmbr_gain_calls - hits
         for k in ("moves", "gain_calls", "gain_cache_hits", "gain_fp_hits"):
             reg.inc("lmbr_" + k, state.stats[k])
-    span.end(moves=state.stats["moves"], gain_calls=state.stats["gain_calls"])
+    span.end(moves=state.stats["moves"], gain_calls=state.stats["gain_calls"],
+             peel_pins_max=state.stats["peel_pins_max"])
     return pl
 
 
@@ -1526,3 +1534,10 @@ ALGORITHMS: dict[str, Callable[..., Placement]] = {
     "pra": pra,
     "lmbr": lmbr,
 }
+
+# Algorithms whose result depends on a query's frequency only through its
+# edge weight (HPA's connectivity cost, LMBR's sum(w_e * span_e); Random
+# reads no edges), so a trace's repeated access sets may be fit as one
+# weighted hyperedge.  IHPA drops residual edges one at a time, DS peels by
+# unweighted degree and PRA's hitting sets count edges: they fit each query.
+WEIGHTED_EDGES = frozenset({"random", "hpa", "lmbr"})

@@ -121,6 +121,32 @@ class Hypergraph:
             edge_weights = np.asarray(edge_weights, dtype=np.float64)
         return Hypergraph(edge_ptr, edge_nodes, node_weights, edge_weights)
 
+    @staticmethod
+    def from_access_sets(
+        queries: Sequence[Iterable[int]],
+        num_nodes: int,
+        node_weights: np.ndarray | None = None,
+        query_weights: np.ndarray | None = None,
+    ) -> "Hypergraph":
+        """One hyperedge per distinct access set of a query trace (the
+        paper's workload model: a query's frequency is its edge weight).
+
+        Each query is canonicalised as its sorted item set; the first
+        occurrence of each set keeps its place in trace order and its weight
+        is the sum, in trace order, of its queries' weights (1.0 each by
+        default).  A trace without repeats gives `from_edges`'s hypergraph
+        exactly, and the objective sum(w_e * span_e) is the trace's."""
+        qw = (np.ones(len(queries)) if query_weights is None
+              else np.asarray(query_weights, dtype=np.float64))
+        merged: dict[tuple[int, ...], float] = {}
+        for q, w in zip(queries, qw.tolist()):
+            key = tuple(sorted(set(q.tolist() if isinstance(q, np.ndarray)
+                                   else q)))
+            merged[key] = merged.get(key, 0.0) + w
+        return Hypergraph.from_edges(
+            list(merged), num_nodes, node_weights,
+            np.fromiter(merged.values(), dtype=np.float64, count=len(merged)))
+
     # ------------------------------------------------------------- properties
     @property
     def num_nodes(self) -> int:

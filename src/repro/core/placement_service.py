@@ -6,7 +6,10 @@ by the framework's data pipeline, MoE runtime and checkpoint manager:
   * PlacementPlan   — frozen result; JSON-serializable; answers
     `partitions_of(item)`, `select(query)` (greedy-set-cover replica
     selection), span statistics.
-  * PlacementService.fit        — one-level placement (paper §4).
+  * PlacementService.fit        — one-level placement (paper §4) of a
+    query trace, fit as one hyperedge per distinct access set weighted by
+    its frequency where the algorithm weighs edges
+    (`Hypergraph.from_access_sets`).
   * PlacementService.fit_sharded — cluster-scale placement through the
     `repro.scale` pipeline: workload sharding (connected components + HPA
     coarse cut), parallel per-shard fits (process pool with a bit-identical
@@ -32,7 +35,7 @@ import numpy as np
 
 from .. import flags as _flags
 from .. import obs as _obs
-from .algorithms import ALGORITHMS, lmbr, min_partitions
+from .algorithms import ALGORITHMS, WEIGHTED_EDGES, lmbr, min_partitions
 from .cluster import (
     NodeProfile,
     ensure_durability,
@@ -171,6 +174,22 @@ class HierarchicalPlan:
         return pod_weight * (ps - 1) + (hs - 1)
 
 
+def _trace_hypergraph(queries, num_items, node_weights, query_weights,
+                      algorithm):
+    """The hypergraph an algorithm fits of a query trace: one hyperedge per
+    distinct access set weighted by its frequency
+    (`Hypergraph.from_access_sets`) where the algorithm weighs edges
+    (`WEIGHTED_EDGES`), else one per query.  Every entry point that takes
+    a query list builds it here, traced as a ``fit.merge`` span with args
+    ``queries`` and ``edges``."""
+    build = (Hypergraph.from_access_sets if algorithm in WEIGHTED_EDGES
+             else Hypergraph.from_edges)
+    with _obs.tracer().span("fit.merge", queries=len(queries)) as sp:
+        hg = build(queries, num_items, node_weights, query_weights)
+        sp.set(edges=hg.num_edges)
+    return hg
+
+
 class PlacementService:
     def __init__(self, algorithm: str = "lmbr", seed: int = 0, nruns: int = 2):
         if algorithm not in ALGORITHMS:
@@ -237,10 +256,6 @@ class PlacementService:
         )
         if capacity is None:
             raise ValueError("pass capacity or a NodeProfile")
-        hg = Hypergraph.from_edges(
-            queries, num_nodes=num_items,
-            node_weights=node_weights, edge_weights=query_weights,
-        )
         fn = ALGORITHMS[self.algorithm]
         algo_kwargs = {}
         if profile is not None:
@@ -249,10 +264,14 @@ class PlacementService:
             algo_kwargs["node_cost"] = profile.access_cost
         with _obs.tracer().span("service.fit", algorithm=self.algorithm,
                                 n=num_partitions) as sp:
+            hg = _trace_hypergraph(queries, num_items, node_weights,
+                                   query_weights, self.algorithm)
             pl = fn(hg, num_partitions, capacity, seed=self.seed,
                     nruns=self.nruns, **algo_kwargs)
             st = pl.stats or {}
             sp.set(moves=st.get("moves"), gain_calls=st.get("gain_calls"))
+        pl.stats = dict(pl.stats or {}, queries=len(queries),
+                        edges=hg.num_edges)
         pl.validate()
         self._apply_durability(
             pl, profile, num_partitions, capacity, durability_eps
@@ -303,10 +322,8 @@ class PlacementService:
                     "pass weights inside the Hypergraph, not alongside it"
                 )
         else:
-            hg = Hypergraph.from_edges(
-                workload, num_nodes=num_items,
-                node_weights=node_weights, edge_weights=query_weights,
-            )
+            hg = _trace_hypergraph(workload, num_items, node_weights,
+                                   query_weights, self.algorithm)
         with _obs.tracer().span("service.fit_sharded",
                                 algorithm=self.algorithm, n=num_partitions):
             res = fit_sharded_placement(
@@ -338,8 +355,8 @@ class PlacementService:
         pod_plan = self.fit(
             queries, num_items, num_pods, pod_capacity, node_weights
         )
-        hg = Hypergraph.from_edges(queries, num_nodes=num_items,
-                                   node_weights=node_weights)
+        hg = _trace_hypergraph(queries, num_items, node_weights, None,
+                               self.algorithm)
         host_member = np.zeros(
             (num_pods * hosts_per_pod, num_items), dtype=bool
         )
@@ -349,7 +366,7 @@ class PlacementService:
             if len(pod_items) == 0:
                 continue
             # queries restricted to this pod's replica of their items
-            local_queries = []
+            local_queries, local_weights = [], []
             mask = np.zeros(num_items, dtype=bool)
             mask[pod_items] = True
             for e in range(hg.num_edges):
@@ -357,12 +374,13 @@ class PlacementService:
                 lq = q[mask[q]]
                 if len(lq) >= 2:
                     local_queries.append(lq)
+                    local_weights.append(hg.edge_weights[e])
             remap = np.full(num_items, -1, dtype=np.int64)
             remap[pod_items] = np.arange(len(pod_items))
-            sub_hg = Hypergraph.from_edges(
-                [remap[q] for q in local_queries] or [[]],
-                num_nodes=len(pod_items),
-                node_weights=hg.node_weights[pod_items],
+            sub_hg = _trace_hypergraph(
+                [remap[q] for q in local_queries] or [[]], len(pod_items),
+                hg.node_weights[pod_items], local_weights or None,
+                self.algorithm,
             )
             sub_pl = fn(
                 sub_hg, hosts_per_pod, host_capacity,
@@ -398,17 +416,17 @@ class PlacementService:
         plan to swap atomically — a warm-started refit only adds replicas,
         so the schedule is pure copies and serving from the union layout
         while they stream in never loses coverage."""
-        hg = Hypergraph.from_edges(
-            queries, num_nodes=plan.member.shape[1],
-            node_weights=plan.node_weights,
-        )
         with _obs.tracer().span("service.refit", max_moves=max_moves):
+            hg = _trace_hypergraph(queries, plan.member.shape[1],
+                                   plan.node_weights, None, "lmbr")
             pl = lmbr(
                 hg, plan.num_partitions, plan.capacity,
                 seed=self.seed, initial=plan.as_placement(),
                 max_moves=max_moves, dest_mask=dest_mask,
                 node_cost=profile.access_cost if profile is not None else None,
             )
+        pl.stats = dict(pl.stats or {}, queries=len(queries),
+                        edges=hg.num_edges)
         pl.validate()
         new_plan = PlacementPlan(
             pl.member, plan.capacity, plan.node_weights,

@@ -434,7 +434,8 @@ class WorkloadCover:
 
 def _cover_bucket(edge_ptr, edge_nodes, member, b_idx, W, spans, pin_parts):
     """Run batched greedy cover for one word-count bucket.  Returns the
-    per-round chosen matrix ch (B, R) with -1 padding."""
+    per-round chosen matrix ch (B, R) with -1 padding, and the backend
+    that resolved it: ``device`` (the device round loop) or ``host``."""
     sizes = edge_ptr[b_idx + 1] - edge_ptr[b_idx]
     B = len(b_idx)
     loc_ptr = np.zeros(B + 1, dtype=np.int64)
@@ -502,7 +503,7 @@ def _cover_bucket(edge_ptr, edge_nodes, member, b_idx, W, spans, pin_parts):
         spans[b_idx] = (ch >= 0).sum(axis=1)
         _attribute_pins(ch, member, b_idx, edge_ptr, pin_e, pos, pins,
                         pin_parts)
-        return ch
+        return ch, "device"
 
     rounds: list[tuple[np.ndarray, np.ndarray]] = []
     if W == 1:
@@ -563,7 +564,7 @@ def _cover_bucket(edge_ptr, edge_nodes, member, b_idx, W, spans, pin_parts):
         reg.inc("cover_rounds", R, backend="host")
     spans[b_idx] = (ch >= 0).sum(axis=1)
     _attribute_pins(ch, member, b_idx, edge_ptr, pin_e, pos, pins, pin_parts)
-    return ch
+    return ch, "host"
 
 
 def _attribute_pins(ch, member, b_idx, edge_ptr, pin_e, pos, pins, pin_parts):
@@ -597,7 +598,9 @@ def batched_cover_csr(
     one popcount matrix op per greedy round per size bucket instead of E
     Python loops.  Queries must be pin-deduplicated (Hypergraph CSR edges
     always are).  Traced as one ``cover.batch`` span (args ``edges`` and
-    the call's ``host_rounds``, ``device_rounds``, ``accel_gain_rounds``)."""
+    the call's ``host_rounds``, ``device_rounds``, ``accel_gain_rounds``)
+    around one ``cover.bucket`` span per word-count bucket (args
+    ``words``, ``edges``, ``backend``)."""
     tr = _obs.tracer()
     with tr.span("cover.batch", edges=len(edge_ptr) - 1) as sp:
         e0 = dict(ENGINE_COUNTERS) if tr.active else None
@@ -624,8 +627,11 @@ def _batched_cover(edge_ptr, edge_nodes, member, with_pin_parts):
     bucket_chosen: list[tuple[np.ndarray, np.ndarray]] = []
     for W in np.unique(words[sizes > 0]) if E else []:
         b_idx = np.flatnonzero((words == W) & (sizes > 0))
-        ch = _cover_bucket(edge_ptr, edge_nodes, member, b_idx, int(W),
-                           spans, pin_parts)
+        with _obs.tracer().span("cover.bucket", words=int(W),
+                                edges=len(b_idx)) as bucket_span:
+            ch, backend = _cover_bucket(edge_ptr, edge_nodes, member, b_idx,
+                                        int(W), spans, pin_parts)
+            bucket_span.set(backend=backend)
         bucket_chosen.append((b_idx, ch))
 
     cover_ptr = np.zeros(E + 1, dtype=np.int64)

@@ -1,5 +1,7 @@
 """Unit + property tests for the hypergraph substrate."""
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -116,3 +118,54 @@ def test_peel_respects_weight_budget(edges, budget):
     hg = Hypergraph.from_edges(edges, num_nodes=20)
     nodes = hg.k_densest_nodes(budget)
     assert hg.node_weights[nodes].sum() <= budget + 1e-9
+
+
+def _counter_merge(queries, weights=None):
+    """Plain reference: a Counter of canonical item sets, which keeps
+    first-occurrence order, and the weights summed in trace order."""
+    keys = [tuple(sorted(set(int(v) for v in q))) for q in queries]
+    w = [1.0] * len(keys) if weights is None else [float(x) for x in weights]
+    order = list(collections.Counter(keys))
+    total = dict.fromkeys(order, 0.0)
+    for k, x in zip(keys, w):
+        total[k] += x
+    return order, [total[k] for k in order]
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_from_access_sets_matches_counter_reference(weighted):
+    rng = np.random.default_rng(5)
+    base = [rng.choice(30, size=rng.integers(1, 9), replace=False)
+            for _ in range(12)]
+    queries = []
+    for _ in range(200):
+        q = base[int(rng.integers(len(base)))]
+        # the same set with duplicate items, in another order, as a list
+        q = np.concatenate([q, q[: int(rng.integers(0, len(q) + 1))]])
+        rng.shuffle(q)
+        queries.append(q.tolist() if rng.random() < 0.5 else q)
+    weights = rng.random(len(queries)) * 3 if weighted else None
+    hg = Hypergraph.from_access_sets(queries, 30, query_weights=weights)
+    order, want_w = _counter_merge(queries, weights)
+    assert [tuple(hg.edge(e).tolist()) for e in range(hg.num_edges)] == order
+    assert hg.edge_weights.tolist() == want_w
+    assert hg.num_edges == len(base)
+    assert np.array_equal(hg.node_weights, np.ones(30))
+
+
+def test_from_access_sets_without_repeats_is_from_edges():
+    rng = np.random.default_rng(8)
+    # query i alone holds item i, with up to 10 of items 50..59
+    queries = [rng.permutation(np.append(
+        rng.choice(np.arange(50, 60), size=int(k), replace=False), i))
+        for i, k in enumerate(rng.integers(0, 11, size=50))]
+    nw = rng.random(60) + 0.5
+    qw = rng.random(50)
+    for qs, kw in ((queries, {}), ([np.sort(q) for q in queries], {}),
+                   (queries, {"query_weights": qw})):
+        merged = Hypergraph.from_access_sets(qs, 60, node_weights=nw, **kw)
+        plain = Hypergraph.from_edges(qs, num_nodes=60, node_weights=nw,
+                                      edge_weights=kw.get("query_weights"))
+        assert merged.equals(plain)
+    assert Hypergraph.from_access_sets([], 3).equals(
+        Hypergraph.from_edges([], num_nodes=3))

@@ -287,11 +287,12 @@ def test_off_vs_trace_bit_identical_fit_and_serve():
 
 # ------------------------------------------------- the fit's span tree
 FIT_TREE = {
-    "service.fit": {"fit.lmbr"},
+    "service.fit": {"fit.merge", "fit.lmbr"},
     "fit.lmbr": {"fit.hpa", "lmbr.init", "lmbr.gain", "lmbr.refresh"},
     "fit.hpa": {"fit.hpa.coarsen", "fit.hpa.refine"},
     "lmbr.init": {"cover.batch"},
     "lmbr.refresh": {"cover.batch"},
+    "cover.batch": {"cover.bucket"},
 }
 
 

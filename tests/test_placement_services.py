@@ -152,3 +152,110 @@ def test_shard_placement_beats_random():
     rnd = plan_shard_placement(recipes, 100, 12, capacity=30, algorithm="random3")
     pra = plan_shard_placement(recipes, 100, 12, capacity=30, algorithm="pra3")
     assert pra.avg_span(recipes) < rnd.avg_span(recipes)
+
+
+def _repeating_trace(seed, n=300):
+    """A trace that repeats 40 distinct access sets, some given with a
+    duplicate item or in another item order."""
+    base = random_workload(num_items=120, num_queries=40, density=6,
+                           seed=seed).queries
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in rng.integers(0, len(base), size=n):
+        q = list(base[int(i)])
+        out.append(q[::-1] + q[:1] if rng.random() < 0.3 else q)
+    return out
+
+
+@pytest.mark.parametrize("algorithm", ["lmbr", "hpa", "random", "ihpa", "ds",
+                                       "pra"])
+def test_fit_merges_repeats_into_weighted_access_sets(algorithm):
+    from repro.core import ALGORITHMS, WEIGHTED_EDGES, Hypergraph
+
+    trace = _repeating_trace(4)
+    hg = Hypergraph.from_access_sets(trace, 120)
+    assert hg.num_edges < len(trace) and hg.edge_weights.sum() == len(trace)
+    svc = PlacementService(algorithm, seed=0)
+    a = svc.fit(trace, 120, 8, 30)
+    assert a.stats["queries"] == len(trace)
+    if algorithm not in WEIGHTED_EDGES:
+        # repeats count as edges in IHPA's drop, DS's degrees and PRA's
+        # hitting sets: these fit every query as its own edge
+        pl = ALGORITHMS[algorithm](Hypergraph.from_edges(trace, 120), 8, 30,
+                                   seed=0, nruns=2)
+        assert np.array_equal(a.member, pl.member)
+        assert a.stats["edges"] == len(trace)
+        return
+    merged = [hg.edge(e) for e in range(hg.num_edges)]
+    b = svc.fit(merged, 120, 8, 30, query_weights=hg.edge_weights)
+    assert np.array_equal(a.member, b.member)
+    assert b.stats["queries"] == len(merged)
+    assert a.stats["edges"] == b.stats["edges"] == hg.num_edges
+    for k in ("moves", "gain_calls"):
+        assert a.stats.get(k) == b.stats.get(k)
+
+
+@pytest.mark.parametrize("algorithm", ["lmbr", "pra"])
+def test_every_entry_point_fits_one_hypergraph(algorithm):
+    """fit_sharded and fit_hierarchical build a query list's hypergraph as
+    fit does: merged where the algorithm weighs edges, else per query."""
+    from repro import flags, obs
+    from repro.core import WEIGHTED_EDGES, Hypergraph
+
+    trace = _repeating_trace(5)
+    build = (Hypergraph.from_access_sets if algorithm in WEIGHTED_EDGES
+             else Hypergraph.from_edges)
+    hg = build(trace, 120)
+    svc = PlacementService(algorithm, seed=0)
+    a = svc.fit_sharded(trace, 8, 30, num_items=120, num_shards=2)
+    b = svc.fit_sharded(hg, 8, 30, num_shards=2)
+    assert np.array_equal(a.member, b.member)
+    flags.FLAGS["obs_level"] = "trace"
+    obs.reset()
+    try:
+        svc.fit_hierarchical(trace, 120, 2, 4, 30)
+        events = [e["args"] for e in obs.tracer().events
+                  if e["name"] == "fit.merge"]
+    finally:
+        flags.FLAGS["obs_level"] = "off"
+        obs.reset()
+    # the pod level's fit, then the host level's own build of the trace
+    assert [(e["queries"], e["edges"]) for e in events[:2]] == [
+        (len(trace), hg.num_edges)] * 2
+
+
+def test_fit_without_repeats_is_the_unmerged_fit(queries):
+    from repro.core import ALGORITHMS, Hypergraph
+
+    assert len({tuple(sorted(q)) for q in queries}) == len(queries)
+    plan = PlacementService("lmbr", seed=0).fit(queries, 120, 8, 30)
+    pl = ALGORITHMS["lmbr"](Hypergraph.from_edges(queries, num_nodes=120),
+                            8, 30, seed=0, nruns=2)
+    assert np.array_equal(plan.member, pl.member)
+    assert plan.stats["edges"] == plan.stats["queries"] == len(queries)
+    for k in ("moves", "gain_calls", "peel_pairs", "peel_pins_max"):
+        assert plan.stats[k] == pl.stats[k]
+
+
+def test_fit_merge_span_and_refit_merge():
+    from repro import flags, obs
+
+    trace = _repeating_trace(6)
+    flags.FLAGS["obs_level"] = "trace"
+    obs.reset()
+    try:
+        svc = PlacementService("hpa", seed=0)
+        plan = svc.fit(trace, 120, 10, 30)
+        plan2 = svc.refit(plan, trace + trace[:7])
+        events = [e for e in obs.tracer().events if e["name"] == "fit.merge"]
+    finally:
+        flags.FLAGS["obs_level"] = "off"
+        obs.reset()
+    edges = plan.stats["edges"]
+    assert [(e["args"]["queries"], e["args"]["edges"]) for e in events] == [
+        (len(trace), edges), (len(trace) + 7, edges)]
+    # each merge is inside its request span
+    assert all(e["args"]["fit"] is not None for e in events)
+    assert plan2.stats["queries"] == len(trace) + 7
+    assert plan2.stats["edges"] == edges
+    assert (plan2.member >= plan.member).all()

@@ -2,7 +2,7 @@
 
 The gain matrix is integer popcount math, so every backend — numpy oracle,
 jitted jnp, Pallas kernel in interpret mode — must agree EXACTLY, including
-over the padding seams (query-batch pow2 pad, partition-axis 128 pad,
+over the padding seams (query-batch block pad, partition-axis 128 pad,
 uint64 -> uint32 lane split).  ``"pallas"`` is the compiled kernel: on a
 TPU it must match too, anywhere else it must refuse to run rather than
 quietly interpret."""
@@ -17,10 +17,11 @@ from repro.kernels.span_gain.ref import span_gain_ref
 
 ON_TPU = jax.default_backend() == "tpu"
 
-# (A, N, W): odd batch sizes straddle the pow2 pad, N > 128 straddles the
-# lane pad, W > 1 exercises the multi-word reduce
+# (A, N, W): odd batch sizes straddle the block pad, A > 256 spans two
+# blocks, N > 128 straddles the lane pad, W > 1 exercises the multi-word
+# reduce
 SHAPES = [(1, 1, 1), (3, 5, 2), (17, 35, 2), (40, 7, 6), (64, 130, 1),
-          (9, 129, 3)]
+          (9, 129, 3), (300, 40, 11)]
 
 
 @pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
@@ -41,6 +42,29 @@ def test_backends_match_oracle(shape, force):
     got = span_gains(codes, rem, force=force)
     assert got.dtype == np.int64
     np.testing.assert_array_equal(got, want)
+
+
+def test_gain_rounds_compile_one_shape_per_word_width(monkeypatch):
+    """Every accelerated call runs in (BLOCK_ROWS, N, W2) blocks, whatever
+    the round's active count: a shrinking active set compiles nothing
+    new."""
+    from repro.kernels.span_gain import ops
+
+    seen = []
+
+    def record(c32, r32):
+        seen.append((c32.shape, r32.shape))
+        return np.asarray(ops.span_gain_jnp(c32, r32))
+
+    monkeypatch.setattr(ops, "_JNP_GAINS", record)
+    rng = np.random.default_rng(3)
+    for a in (1, 100, 256, 257, 700):
+        codes = rng.integers(0, 2**63, size=(a, 40, 3), dtype=np.uint64)
+        rem = rng.integers(0, 2**63, size=(a, 3), dtype=np.uint64)
+        got = span_gains(codes, rem, force="jax")
+        np.testing.assert_array_equal(got, span_gain_ref(codes, rem))
+    assert set(seen) == {((ops.BLOCK_ROWS, 40, 6), (ops.BLOCK_ROWS, 6))}
+    assert len(seen) == 1 + 1 + 1 + 2 + 3
 
 
 def test_kernel_interpret_matches_jnp_ref():

@@ -7,7 +7,9 @@ each kernel of the fit -> serve path is compiled here at the shapes the
 engine emits: the span-gain kernel at the serving microbatch width, the
 cover round loop at the largest bucket `chip_smoke.py`'s 75k-query fit over
 256 partitions uploads, and the lockstep-peel kernel at a small and the
-largest (K, U) class the LMBR dispatcher densifies.  Each compile must contain the
+largest (K, U) class the LMBR dispatcher densifies; and the span-gain
+kernel and round loop at the shapes of ``tpchcol40-fit``'s wide buckets
+over 40 partitions.  Each compile must contain the
 Pallas custom call (kernels) and fit one chip's 16 GiB of HBM.
 
 The topology is described inside a module fixture, never at import: only
@@ -70,6 +72,35 @@ def test_span_gain_compiles(one_chip, w2):
         _arg(one_chip, (a, w2), jnp.uint32),
     ).compile()
     _check(compiled, kernel=True)
+
+
+@pytest.mark.parametrize("w2", [2, 22, 24])
+def test_span_gain_compiles_tpch_columnar(one_chip, w2):
+    """``tpchcol40-fit``'s gain rounds over 40 partitions (the lane axis
+    padded to 128), each a block of ``BLOCK_ROWS`` queries: its single-word
+    buckets, its widest (Q9's 673 items, 11 words) and one word past it."""
+    from repro.kernels.span_gain.kernel import span_gain
+    from repro.kernels.span_gain.ops import BLOCK_ROWS
+
+    compiled = span_gain.lower(
+        _arg(one_chip, (BLOCK_ROWS, w2, 128), jnp.uint32),
+        _arg(one_chip, (BLOCK_ROWS, w2), jnp.uint32),
+    ).compile()
+    _check(compiled, kernel=True)
+
+
+def test_cover_round_loop_compiles_tpch_columnar(one_chip):
+    """``tpchcol40-fit``'s one bucket past ``span_round_threshold``: some
+    730 eight-word queries (Q8, Q18, Q19, Q21) over 40 partitions, padded
+    to 1024, every greedy round on the device."""
+    from repro.core.setcover import _round_loop_fn
+
+    B, N, W2, Rmax = 1024, 40, 16, 40
+    compiled = _round_loop_fn(B, N, W2, Rmax).lower(
+        _arg(one_chip, (W2, B, N), jnp.uint32),
+        _arg(one_chip, (W2, B), jnp.uint32),
+    ).compile()
+    _check(compiled, kernel=False)
 
 
 def test_cover_round_loop_compiles(one_chip):
