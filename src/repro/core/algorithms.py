@@ -459,9 +459,12 @@ class _LMBRState:
                  or (np.all(nw == np.rint(nw)) and float(nw.sum()) < 2 ** 24))
         )
         # peel_pins_max: the widest peel batch's costly pins per round,
-        # sum(pins) / Umax (a lockstep round peels one item of every pair)
+        # sum(pins) / Umax (a lockstep round peels one item of every pair);
+        # peel_items / peel_classes: pool items and the twin classes the
+        # scalar peel keeps one heap entry for, over the peeled pairs
         self.stats = dict(gain_calls=0, gain_cache_hits=0, gain_fp_hits=0,
-                          peel_pairs=0, peel_pins_max=0.0, moves=0,
+                          peel_pairs=0, peel_pins_max=0.0, peel_items=0,
+                          peel_classes=0, moves=0,
                           dense_peel_calls=0, dense_peel_skips=0,
                           int_exact=self._int_exact)
 
@@ -621,7 +624,8 @@ class _LMBRState:
         )
 
     def _peel_with_traj(self, proj: list[tuple], backend: str):
-        """Peel projected pairs, returning {key: (pool, fp, traj)}.  The
+        """Peel projected pairs, returning ({key: (pool, fp, traj)},
+        classes), classes as ``_lmbr_peel_scalar`` counts them.  The
         dense device backends only engage on the integer-exact domain; a
         requested device peel outside it is counted in ``dense_peel_skips``
         and runs the host's per-pair trajectory peel
@@ -684,7 +688,11 @@ class _LMBRState:
                     continue
                 peel_list.append(p)
             if peel_list:
+                peeled, n_classes = self._peel_with_traj(peel_list, backend)
+                n_items = sum(len(p[3]) for p in peel_list)
                 self.stats["peel_pairs"] += len(peel_list)
+                self.stats["peel_items"] += n_items
+                self.stats["peel_classes"] += n_classes
                 self.stats["peel_pins_max"] = max(
                     self.stats["peel_pins_max"],
                     sum(len(p[4]) for p in peel_list)
@@ -693,7 +701,8 @@ class _LMBRState:
                 if reg.active:
                     reg.inc("lmbr_peel_rounds")
                     reg.inc("lmbr_peel_pairs", len(peel_list))
-                peeled = self._peel_with_traj(peel_list, backend)
+                    reg.inc("lmbr_peel_items", n_items)
+                    reg.inc("lmbr_peel_classes", n_classes)
                 for p in peel_list:
                     k = p[0]
                     pool, fp, traj = peeled[k]
@@ -1046,10 +1055,12 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
     free-space-independent trajectories come back; selection happens in
     ``_eval_traj``.  Caller guarantees the integer-exact weight domain, so
     the f32 trajectories are bit-identical to the host f64 peel's.
-    Returns {key: (pool, fp, traj)} like ``_lmbr_peel_scalar``."""
+    Returns ({key: (pool, fp, traj)}, classes) like ``_lmbr_peel_scalar``;
+    a densified pair counts each of its items as a class."""
     force = _DENSE_PEELS[backend]
     node_w = state.hg.node_weights
     out: dict[tuple[int, int], tuple] = {}
+    n_classes = 0
     classes: dict[tuple[int, int], list[tuple]] = {}
     huge: list[tuple] = []
     for p in proj:
@@ -1088,48 +1099,79 @@ def _lmbr_peel_dense(state: _LMBRState, proj: list[tuple], backend: str):
                     if R else None
                 )
                 out[p[0]] = (p[3], (p[2], p[3], p[4], p[5], p[6]), traj)
+                n_classes += len(p[3])
     if huge:
-        out.update(_lmbr_peel_scalar(state, huge))
-    return out
+        peeled, n_huge = _lmbr_peel_scalar(state, huge)
+        out.update(peeled)
+        n_classes += n_huge
+    return out, n_classes
 
 
 def _lmbr_peel_scalar(state: _LMBRState, proj: list[tuple]):
     """Per-pair heap peel with trajectories over projected pairs: Python
-    lists and a heap of (degree, column), one pair at a time.
+    lists and a heap of twin classes, one pair at a time.
 
-    Returns {key: (pool, fp, traj)}: the free-space-independent
+    Returns ({key: (pool, fp, traj)}, classes): the free-space-independent
     head-of-round states (peeled column, weight, benefit) that
-    ``_eval_traj`` and the trajectory cache select from.  It makes the
-    float operations of ``_lmbr_peel_flat`` and of the oracle in the same
-    order, so a selection over its trajectory is bit-identical to theirs.
-    Degrees are left folds over the pins in scan order (edges ascending,
-    pins in edge order); each round
-    records the head-of-round weight and benefit, peels the lowest degree
-    (ties -> lowest column, as ``argmin``), and retires the item's alive
-    edges in ascending order, each first from the benefit and then from
-    its alive pins' degrees.  A heap entry is live only while its degree
-    equals the column's exactly, so no tolerance can reorder a near-tie."""
+    ``_eval_traj`` and the trajectory cache select from, and the number of
+    twin classes peeled.  It makes the float operations of
+    ``_lmbr_peel_flat`` and of the oracle in the same order, so a
+    selection over its trajectory is bit-identical to theirs.
+
+    Twins are columns with the same incident kept edges.  An item's degree
+    is the left fold of its edges' weights in ascending edge order, and a
+    retired edge subtracts its weight from every alive pin, so twins hold
+    the same float at every round: the peel keeps one degree and one heap
+    entry (degree, lowest alive column, class) per class.  Each round
+    records the head-of-round weight and benefit, peels the head column of
+    the lowest entry (ties -> lowest column, as ``argmin``), and retires
+    its alive edges in ascending order, each first from the benefit and
+    then from the degree of every class on it that still has members.  An
+    entry is live only while its column heads its class and its degree
+    equals the class's exactly, so no tolerance can reorder a near-tie."""
     node_w = state.hg.node_weights
     heappop, heappush = heapq.heappop, heapq.heappush
     out: dict[tuple[int, int], tuple] = {}
+    n_classes = 0
     for p in proj:
         key, _, we_a, uniq, loc_a, cedge_a, pin_cnt, totw = p
         we = we_a.tolist()
         nw = node_w[uniq].tolist()
         n_alive = len(nw)
-        deg = [0.0] * n_alive
-        inc: list[list[int]] = [[] for _ in range(n_alive)]
+        # each column's edges, ascending: the scan order sorted by column
+        inc = cedge_a[loc_a.argsort(kind="stable")].tolist()
+        cnt = np.bincount(loc_a, minlength=n_alive).tolist()
+        # twin classes, found from the last column down: head[k] is the
+        # class's lowest alive column and nxt[c] the member after c
+        cls_of: dict[tuple, int] = {}
+        head: list[int] = []
+        nxt = [-1] * n_alive
+        edges_of: list[tuple] = []
+        deg: list[float] = []
         pins: list[list[int]] = [[] for _ in we]
-        for c, e in zip(loc_a.tolist(), cedge_a.tolist()):
-            deg[c] += we[e]
-            inc[c].append(e)
-            pins[e].append(c)
+        hi = len(inc)
+        for c in range(n_alive - 1, -1, -1):
+            lo = hi - cnt[c]
+            t = tuple(inc[lo:hi])
+            hi = lo
+            k = cls_of.setdefault(t, len(head))
+            if k < len(head):
+                nxt[c] = head[k]
+                head[k] = c
+                continue
+            head.append(c)
+            edges_of.append(t)
+            d = 0.0
+            for e in t:
+                d += we[e]
+                pins[e].append(k)
+            deg.append(d)
+        n_classes += len(head)
+        heap = list(zip(deg, head, range(len(head))))
+        heapq.heapify(heap)
         benefit = we[0]
         for w in we[1:]:
             benefit += w
-        heap = [(d, c) for c, d in enumerate(deg)]
-        heapq.heapify(heap)
-        alive = [True] * n_alive
         edge_alive = [True] * len(we)
         rj: list[int] = []
         rtot: list[float] = []
@@ -1137,29 +1179,38 @@ def _lmbr_peel_scalar(state: _LMBRState, proj: list[tuple]):
         while benefit > 1e-12 and n_alive:
             rtot.append(totw)
             rben.append(benefit)
-            d, j = heappop(heap)
-            while not (alive[j] and d == deg[j]):
-                d, j = heappop(heap)
+            d, j, k = heappop(heap)
+            while head[k] != j or d != deg[k]:
+                d, j, k = heappop(heap)
             rj.append(j)
-            alive[j] = False
             n_alive -= 1
             totw -= nw[j]
-            for e in inc[j]:
-                if edge_alive[e]:
-                    edge_alive[e] = False
-                    w = we[e]
-                    benefit -= w
-                    for u in pins[e]:
-                        if alive[u]:
-                            deg[u] -= w
-                            heappush(heap, (deg[u], u))
+            h = head[k] = nxt[j]
+            es = edges_of[k]
+            if es:
+                # the first peel of a class retires all of its edges
+                edges_of[k] = ()
+                changed = {k} if h >= 0 else set()
+                for e in es:
+                    if edge_alive[e]:
+                        edge_alive[e] = False
+                        w = we[e]
+                        benefit -= w
+                        for u in pins[e]:
+                            if head[u] >= 0:
+                                deg[u] -= w
+                                changed.add(u)
+                for u in changed:
+                    heappush(heap, (deg[u], head[u], u))
+            elif h >= 0:
+                heappush(heap, (deg[k], h, k))
         traj = (
             (np.array(rj, dtype=np.int64), np.array(rtot, dtype=np.float64),
              np.array(rben, dtype=np.float64))
             if rj else None
         )
         out[key] = (uniq, (we_a, uniq, loc_a, cedge_a, pin_cnt), traj)
-    return out
+    return out, n_classes
 
 
 def _lmbr_peel_flat(state: _LMBRState, proj: list[tuple]):
@@ -1522,7 +1573,9 @@ def lmbr(
         for k in ("moves", "gain_calls", "gain_cache_hits", "gain_fp_hits"):
             reg.inc("lmbr_" + k, state.stats[k])
     span.end(moves=state.stats["moves"], gain_calls=state.stats["gain_calls"],
-             peel_pins_max=state.stats["peel_pins_max"])
+             peel_pins_max=state.stats["peel_pins_max"],
+             peel_items=state.stats["peel_items"],
+             peel_classes=state.stats["peel_classes"])
     return pl
 
 

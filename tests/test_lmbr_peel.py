@@ -8,11 +8,13 @@ on weighted instances, free pins, and zero-capacity destinations; and the
 epoch-keyed gain cache never changes any result.
 """
 
+import heapq
+
 import numpy as np
 import pytest
 
 from repro import flags
-from repro.core import hpa_partition, lmbr, random_workload
+from repro.core import algorithms, hpa_partition, lmbr, random_workload
 from repro.core.algorithms import (
     _assign_to_placement,
     _eval_traj,
@@ -27,23 +29,66 @@ from repro.core.algorithms import (
 from repro.core.hypergraph import Hypergraph
 
 
-def _random_state(rng, *, weighted_nodes=False, weighted_edges=False,
-                  num_items=60, num_edges=150, num_parts=8, capacity=40.0):
-    """A random placement state: random hyperedges over `num_items` items,
-    every item on >= 1 random partition plus random extra replicas."""
+def _twin_edges(rng, num_items, num_edges, num_parts):
+    """Twin-heavy hyperedges, like a column store's month blocks: items in
+    blocks of 2-6 scattered ids, each block on one partition and read only
+    whole, so a block's items share one edge set (and are twins in every
+    projection that keeps them all); mixed with random edges over all items,
+    which split some blocks.  Edge weights come from a few non-integer
+    values, their one-ulp neighbours and a tiny one, so degrees of
+    different classes tie exactly or nearly."""
+    perm = rng.permutation(num_items)
+    blocks, lo = [], 0
+    while lo < num_items:
+        size = int(rng.integers(2, 7))
+        blocks.append(np.sort(perm[lo: lo + size]))
+        lo += size
+    assign = np.empty(num_items, dtype=np.int64)
+    for b in blocks:
+        assign[b] = rng.integers(num_parts)
     edges = []
     for _ in range(num_edges):
-        size = int(rng.integers(2, 8))
-        edges.append(rng.choice(num_items, size=size, replace=False))
-    node_w = (
-        rng.uniform(0.5, 4.0, size=num_items) if weighted_nodes else None
-    )
-    edge_w = (
-        rng.uniform(0.1, 3.0, size=num_edges) if weighted_edges else None
-    )
+        if rng.random() < 0.8:
+            pick = rng.choice(len(blocks), size=int(rng.integers(1, 4)),
+                              replace=False)
+            edges.append(np.concatenate([blocks[i] for i in pick]))
+        else:
+            edges.append(rng.choice(num_items, size=int(rng.integers(2, 6)),
+                                    replace=False))
+    base = np.array([3e-10, 0.3, 0.7, 1.1, 2.35])
+    w = rng.choice(base, size=num_edges)
+    w = np.where(rng.random(num_edges) < 0.3,
+                 np.nextafter(w, rng.choice([0.0, 10.0], size=num_edges)), w)
+    return edges, w, assign
+
+
+def _random_state(rng, *, weighted_nodes=False, weighted_edges=False,
+                  twins=False, num_items=60, num_edges=150, num_parts=8,
+                  capacity=40.0):
+    """A random placement state: random hyperedges over `num_items` items,
+    every item on >= 1 random partition plus random extra replicas.  With
+    ``twins`` the edges, their weights and the first copies come from
+    ``_twin_edges``, and node weights are always drawn."""
+    assign = None
+    if twins:
+        edges, edge_w, assign = _twin_edges(rng, num_items, num_edges,
+                                            num_parts)
+        node_w = rng.uniform(0.5, 4.0, size=num_items)
+    else:
+        edges = []
+        for _ in range(num_edges):
+            size = int(rng.integers(2, 8))
+            edges.append(rng.choice(num_items, size=size, replace=False))
+        node_w = (
+            rng.uniform(0.5, 4.0, size=num_items) if weighted_nodes else None
+        )
+        edge_w = (
+            rng.uniform(0.1, 3.0, size=num_edges) if weighted_edges else None
+        )
     hg = Hypergraph.from_edges(edges, num_nodes=num_items,
                                node_weights=node_w, edge_weights=edge_w)
-    assign = rng.integers(0, num_parts, size=num_items)
+    if assign is None:
+        assign = rng.integers(0, num_parts, size=num_items)
     pl = _assign_to_placement(hg, assign, num_parts, capacity)
     # random extra replicas (creates free pins: items already on dest)
     extra = rng.random((num_parts, num_items)) < 0.08
@@ -61,16 +106,30 @@ def _assert_pair_equal(ref, vec, ctx):
         np.testing.assert_array_equal(it_ref, it_vec, err_msg=str(ctx))
 
 
-@pytest.mark.parametrize("weighted_nodes,weighted_edges", [
-    (False, False), (True, False), (False, True), (True, True),
-])
-def test_peel_matches_oracle_randomized(weighted_nodes, weighted_edges):
-    """Property-style: on randomized (optionally weighted) instances, every
-    (src, dest) pair peels to the oracle's exact (gain, items)."""
-    rng = np.random.default_rng(11 + 2 * weighted_nodes + weighted_edges)
+# instance flavours: the four weightings of plain random edges, and the
+# twin-heavy instance (non-integer node and edge weights)
+FLAVOURS = [
+    pytest.param(n, e, False, id=f"{n}-{e}")
+    for n, e in [(False, False), (True, False), (False, True), (True, True)]
+] + [pytest.param(True, True, True, id="twins")]
+
+
+def _flavour_rng(weighted_nodes, weighted_edges, twins):
+    return np.random.default_rng(
+        11 + 2 * weighted_nodes + weighted_edges + 100 * twins)
+
+
+@pytest.mark.parametrize("weighted_nodes,weighted_edges,twins", FLAVOURS)
+def test_peel_matches_oracle_randomized(weighted_nodes, weighted_edges,
+                                        twins):
+    """Property-style: on randomized (optionally weighted or twin-heavy)
+    instances, every (src, dest) pair peels to the oracle's exact (gain,
+    items)."""
+    rng = _flavour_rng(weighted_nodes, weighted_edges, twins)
     for trial in range(4):
         hg, state = _random_state(
             rng, weighted_nodes=weighted_nodes, weighted_edges=weighted_edges,
+            twins=twins,
         )
         n = state.pl.num_partitions
         pairs = [(s, d) for s in range(n) for d in range(n) if s != d]
@@ -81,18 +140,17 @@ def test_peel_matches_oracle_randomized(weighted_nodes, weighted_edges):
 
 
 @pytest.mark.parametrize("batch", ["one", "all"])
-@pytest.mark.parametrize("weighted_nodes,weighted_edges", [
-    (False, False), (True, False), (False, True), (True, True),
-])
+@pytest.mark.parametrize("weighted_nodes,weighted_edges,twins", FLAVOURS)
 def test_scalar_peel_matches_lockstep_bytes(weighted_nodes, weighted_edges,
-                                            batch):
+                                            twins, batch):
     """The scalar heap peel's trajectories, selected under any free space,
     give the lockstep peel's exact (gain, items), pair by pair and over all
     n(n-1) pairs at once; its pool and fingerprint are the projection's."""
-    rng = np.random.default_rng(11 + 2 * weighted_nodes + weighted_edges)
+    rng = _flavour_rng(weighted_nodes, weighted_edges, twins)
     for trial in range(4):
         hg, state = _random_state(
             rng, weighted_nodes=weighted_nodes, weighted_edges=weighted_edges,
+            twins=twins,
         )
         n = state.pl.num_partitions
         pairs = [(s, d) for s in range(n) for d in range(n) if s != d]
@@ -100,7 +158,7 @@ def test_scalar_peel_matches_lockstep_bytes(weighted_nodes, weighted_edges,
         assert proj
         batches = [[p] for p in proj] if batch == "one" else [proj]
         for b in batches:
-            scalar = _lmbr_peel_scalar(state, b)
+            scalar, _ = _lmbr_peel_scalar(state, b)
             assert scalar.keys() == {p[0] for p in b}
             for p in b:
                 pool, fp, _ = scalar[p[0]]
@@ -144,6 +202,115 @@ def test_host_peel_paths_give_the_same_fit():
         np.testing.assert_array_equal(sc.member, other.member, err_msg=spec)
         for k in ("gain_calls", "moves"):
             assert sc.stats[k] == other.stats[k], (spec, k)
+
+
+def _item_peel(state, proj):
+    """The per-item heap peel: one heap entry (degree, column) per pool
+    item, the same float operations as the twin-class peel.  Returns
+    (out, items) in ``_lmbr_peel_scalar``'s form, each item its own class."""
+    node_w = state.hg.node_weights
+    out, n_items = {}, 0
+    for key, _, we_a, uniq, loc_a, cedge_a, pin_cnt, totw in proj:
+        we, nw = we_a.tolist(), node_w[uniq].tolist()
+        n = len(nw)
+        n_items += n
+        deg = [0.0] * n
+        inc = [[] for _ in range(n)]
+        pins = [[] for _ in we]
+        for c, e in zip(loc_a.tolist(), cedge_a.tolist()):
+            deg[c] += we[e]
+            inc[c].append(e)
+            pins[e].append(c)
+        benefit = we[0]
+        for w in we[1:]:
+            benefit += w
+        heap = [(d, c) for c, d in enumerate(deg)]
+        heapq.heapify(heap)
+        alive, edge_alive = [True] * n, [True] * len(we)
+        rj, rtot, rben = [], [], []
+        while benefit > 1e-12 and n:
+            rtot.append(totw)
+            rben.append(benefit)
+            d, j = heapq.heappop(heap)
+            while not (alive[j] and d == deg[j]):
+                d, j = heapq.heappop(heap)
+            rj.append(j)
+            alive[j] = False
+            n -= 1
+            totw -= nw[j]
+            for e in inc[j]:
+                if edge_alive[e]:
+                    edge_alive[e] = False
+                    benefit -= we[e]
+                    for u in pins[e]:
+                        if alive[u]:
+                            deg[u] -= we[e]
+                            heapq.heappush(heap, (deg[u], u))
+        traj = (np.array(rj), np.array(rtot), np.array(rben)) if rj else None
+        out[key] = (uniq, (we_a, uniq, loc_a, cedge_a, pin_cnt), traj)
+    return out, n_items
+
+
+def test_twin_peel_trajectories_are_the_item_peels():
+    """On the twin-heavy instance every trajectory of the twin-class peel,
+    peeled order included, is byte for byte the per-item peel's."""
+    rng = _flavour_rng(True, True, True)
+    for trial in range(4):
+        hg, state = _random_state(rng, weighted_nodes=True,
+                                  weighted_edges=True, twins=True)
+        n = state.pl.num_partitions
+        _, proj = _lmbr_project(
+            state, [(s, d) for s in range(n) for d in range(n) if s != d])
+        got, classes = _lmbr_peel_scalar(state, proj)
+        want, items = _item_peel(state, proj)
+        assert classes < items
+        for p in proj:
+            a, b = got[p[0]][2], want[p[0]][2]
+            assert (a is None) == (b is None), (trial, p[0])
+            for x, y in zip(a or (), b or ()):
+                assert x.tobytes() == y.tobytes(), (trial, p[0])
+
+
+@pytest.mark.parametrize("twins", [False, True])
+def test_peel_counts_twin_classes(twins, monkeypatch):
+    """``peel_classes`` counts the heap entries the scalar peel keeps: one
+    per item where no two pool items share an edge set (every edge holds
+    two items, so a kept edge has one costly pin), fewer on the twin-heavy
+    instance.  Either way the fit is the reference peel's plan with its
+    gain calls and moves, and has the per-item peel's cache hits (the
+    reference's result-only cache entries hit only while no move
+    intervened, so its hit count differs by design)."""
+    rng = np.random.default_rng(23)
+    if twins:
+        hg = _random_state(rng, weighted_nodes=True, twins=True)[0]
+    else:
+        edges = [rng.choice(60, size=2, replace=False) for _ in range(150)]
+        hg = Hypergraph.from_edges(edges, num_nodes=60,
+                                   node_weights=rng.uniform(0.5, 4.0, 60),
+                                   edge_weights=rng.uniform(0.1, 3.0, 150))
+    fits = {}
+    for spec in ("baseline", "peelreference"):
+        flags.set_variant(spec)
+        try:
+            fits[spec] = lmbr(hg, 8, 40.0, seed=0)
+        finally:
+            flags.reset()
+    monkeypatch.setattr(algorithms, "_lmbr_peel_scalar", _item_peel)
+    fits["item"] = lmbr(hg, 8, 40.0, seed=0)
+    st = fits["baseline"].stats
+    assert st["peel_pairs"] > 0 and st["moves"] > 0
+    assert st["peel_items"] == fits["item"].stats["peel_items"]
+    if twins:
+        assert st["peel_classes"] < st["peel_items"]
+    else:
+        assert st["peel_classes"] == st["peel_items"]
+    for other, keys in (("peelreference", ("gain_calls", "moves")),
+                        ("item", ("gain_calls", "moves", "gain_cache_hits",
+                                  "gain_fp_hits"))):
+        np.testing.assert_array_equal(fits["baseline"].member,
+                                      fits[other].member, err_msg=other)
+        for k in keys:
+            assert st[k] == fits[other].stats[k], (other, k)
 
 
 def test_peel_zero_capacity_dest():

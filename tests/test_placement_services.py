@@ -233,7 +233,8 @@ def test_fit_without_repeats_is_the_unmerged_fit(queries):
                             8, 30, seed=0, nruns=2)
     assert np.array_equal(plan.member, pl.member)
     assert plan.stats["edges"] == plan.stats["queries"] == len(queries)
-    for k in ("moves", "gain_calls", "peel_pairs", "peel_pins_max"):
+    for k in ("moves", "gain_calls", "peel_pairs", "peel_pins_max",
+              "peel_items", "peel_classes"):
         assert plan.stats[k] == pl.stats[k]
 
 

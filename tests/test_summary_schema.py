@@ -32,7 +32,8 @@ BASE_KEYS = {
 }
 LMBR_FIT_KEYS = {
     "fit_moves", "fit_gain_calls", "fit_gain_cache_hits", "fit_gain_fp_hits",
-    "fit_peel_pairs", "fit_peel_pins_max", "fit_peel", "fit_gain_cache",
+    "fit_peel_pairs", "fit_peel_pins_max", "fit_peel_items",
+    "fit_peel_classes", "fit_peel", "fit_gain_cache",
     "fit_lmbr_epochs",
     "fit_cache_hit_rate", "fit_cover_engine", "fit_dense_peel_calls",
     "fit_dense_peel_skips", "fit_int_exact",

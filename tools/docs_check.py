@@ -55,6 +55,7 @@ NON_FLAGS = {"span_gain", "span_gain_calibration", "span_gain_ref",
              "migration_transferred_total", "migration_wasted_total",
              "migration_copies_total", "migration_drops_total",
              "drift_fires_total", "drift_refits_total", "lmbr_moves",
+             "lmbr_peel_items", "lmbr_peel_classes",
              "health_alerts_fired_total", "health_alerts_resolved_total",
              "health_alerts_active"}
 # backticked tokens that should parse as --variant specs
